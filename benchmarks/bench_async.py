@@ -273,6 +273,10 @@ def smoke_n_scaling(out: str | None = None, million: bool = False) -> bool:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="structural gate: one scanned program, no host "

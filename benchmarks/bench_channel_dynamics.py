@@ -119,6 +119,10 @@ def smoke(out: str | None = None) -> bool:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="structural gate: one scanned program, no host "

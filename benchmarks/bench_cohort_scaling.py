@@ -168,6 +168,10 @@ def smoke(out: str | None = None) -> bool:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",

@@ -194,12 +194,15 @@ def run(out: str | None = None) -> dict:
     import jax
 
     t0 = time.perf_counter()
-    acc = _accounting()
-    emit("faults/accounting", 0.0,
-         f"{acc['fault_events']:.0f} in {acc['window']}")
+    # the fl_sim children run FIRST: an accelerator belongs to one process
+    # at a time, so this parent must not initialise a backend (which
+    # _accounting and _byzantine do) until the children have exited
     with tempfile.TemporaryDirectory() as tmp:
         kr = _kill_resume(tmp)
     emit("faults/kill_resume", 0.0, str(kr.get("bitwise_identical")))
+    acc = _accounting()
+    emit("faults/accounting", 0.0,
+         f"{acc['fault_events']:.0f} in {acc['window']}")
     byz = _byzantine()
     emit("faults/byzantine", 0.0,
          f"fedavg={byz['acc_fedavg_fault_free']}->"
@@ -250,4 +253,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     main()

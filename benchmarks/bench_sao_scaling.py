@@ -21,4 +21,7 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

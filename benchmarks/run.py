@@ -8,13 +8,11 @@ from __future__ import annotations
 
 import argparse
 import importlib
-import os
 import sys
 import time
 import traceback
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+from repro.utils.compile_cache import configure_compile_cache
 
 MODULES = [
     ("fig5", "benchmarks.fig5_sao_vs_fedl"),
@@ -37,6 +35,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark keys")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

@@ -770,14 +770,13 @@ def run_rounds(cfg: EngineConfig, *, selector, allocator, aggregator,
             test_ax = None if test_shared else 0
             core = jax.vmap(core, in_axes=(0, 0, 0, 0, 0, test_ax, test_ax))
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 data_spec = P("cohort")
                 test_spec = P() if test_shared else P("cohort")
-                core = shard_map(
+                core = jax.shard_map(
                     core, mesh=mesh,
                     in_specs=(data_spec,) * 5 + (test_spec, test_spec),
-                    out_specs=data_spec, check_rep=False)
+                    out_specs=data_spec, check_vma=False)
         # donate the carry: the (possibly [cohort, N, P]-sized) RoundState
         # buffers update in place across dispatches instead of double-
         # buffering — callers must treat the passed-in state as consumed

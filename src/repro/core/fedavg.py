@@ -19,6 +19,7 @@ registries — construct experiments declaratively with
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass, field
 from typing import Any, List, Optional, Union
@@ -1300,6 +1301,7 @@ class FLExperiment:
                         faults=self.faults,
                         quarantine_after=self.quarantine_after)
         state = self.traced_state()
+        mesh = None
         if self.p_shards:
             # P-axis GSPMD: lay the carry's P-sized dims out over a `model`
             # mesh before dispatch — the scanned program's donated carry
@@ -1311,9 +1313,13 @@ class FLExperiment:
                 state = jax.device_put(
                     state, plane_shardings(state, mesh,
                                            int(state.params.shape[0])))
-        res = fn(state, self._images, self._labels,
-                 self._sizes, fleet_arrays(self.fleet), self.test_images,
-                 self.test_labels)
+        # the mesh rides the trace context so the kernel seams
+        # (repro.kernels.ops) can put their Mosaic calls in a shard_map
+        with (jax.set_mesh(mesh) if mesh is not None
+              else contextlib.nullcontext()):
+            res = fn(state, self._images, self._labels,
+                     self._sizes, fleet_arrays(self.fleet),
+                     self.test_images, self.test_labels)
         self.load_traced_state(res.state,
                                clusters_valid=with_init
                                or self.cluster_labels is not None)

@@ -12,20 +12,31 @@ orders of magnitude slower than the jnp reference (``flat_aggregate``:
 ``RuntimeWarning``. Setting ``REPRO_FORCE_PALLAS=1`` is the escape hatch
 for deliberate interpret-mode validation runs: it silences the warning and
 also flips the ``use_pallas=None`` default to the kernel path everywhere.
+
+Mesh policy (``_on_plane_mesh``): a Mosaic kernel inside a jit that
+spans several devices must sit in a ``shard_map`` — GSPMD cannot partition
+it and lowering fails. Under the ``p_shards`` plane mesh (a context mesh
+with a ``model`` axis, set by ``FLExperiment._run_traced``) every kernel
+call here is wrapped: the plane kernels run on their column shard (the
+distance kernels ``psum`` their per-shard partial sums), and a call whose
+column count does not divide the axis runs whole on every device.
 """
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flat_aggregate import flat_aggregate as _flat_agg
 from repro.kernels.pairwise_l2 import pairwise_l2 as _pairwise
 from repro.kernels.ssd_scan import ssd_scan as _ssd
+from repro.sharding.specs import MODEL_AXIS
 
 
 def _on_tpu() -> bool:
@@ -54,6 +65,36 @@ def _resolve_use_pallas(op: str, use_pallas: bool | None) -> bool:
     return use_pallas
 
 
+_WHOLE = PartitionSpec()                  # every device holds all of it
+_COLS = PartitionSpec(None, MODEL_AXIS)   # [rows, cols] split by column
+
+
+def _on_plane_mesh(kernel, split_specs, cols=None, *, psum: bool = False):
+    """``kernel`` made legal on the context mesh (see the module docstring).
+
+    Without a multi-device ``model`` axis in context this is ``kernel``
+    itself. ``split_specs`` gives each argument's ``PartitionSpec`` when it
+    is split along the plane's ``cols`` columns; that split is taken when
+    ``cols`` divides the axis. Split outputs are column shards, or, with
+    ``psum``, per-shard partial sums added across the axis. Otherwise
+    (``cols`` None or not divisible) every device runs the whole kernel.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    m = (mesh.shape[MODEL_AXIS]
+         if not mesh.empty and MODEL_AXIS in mesh.axis_names else 1)
+    if m == 1:
+        return kernel
+    if cols is None or cols % m:
+        return jax.shard_map(kernel, in_specs=(_WHOLE,) * len(split_specs),
+                             out_specs=_WHOLE, check_vma=False)
+    if psum:
+        return jax.shard_map(lambda *a: jax.lax.psum(kernel(*a), MODEL_AXIS),
+                             in_specs=split_specs, out_specs=_WHOLE,
+                             check_vma=False)
+    return jax.shard_map(kernel, in_specs=split_specs,
+                         out_specs=PartitionSpec(MODEL_AXIS), check_vma=False)
+
+
 def kernel_dispatch(use_pallas: bool | None = None) -> bool:
     """Would this call take the kernel route? The policy of
     ``_resolve_use_pallas`` WITHOUT the off-TPU warning — for callers
@@ -76,7 +117,9 @@ def pairwise_sq_dists(x, c, *, use_pallas: bool | None = None):
     """
     use_pallas = _resolve_use_pallas("pairwise_sq_dists", use_pallas)
     if use_pallas:
-        return _pairwise(x, c, interpret=not _on_tpu())
+        kernel = functools.partial(_pairwise, interpret=not _on_tpu())
+        return _on_plane_mesh(kernel, (_COLS, _COLS), x.shape[1],
+                              psum=True)(x, c)
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
     xn = jnp.sum(jnp.square(x), axis=1, keepdims=True)
@@ -111,7 +154,8 @@ def flat_aggregate(flat, weights, *, mask=None, normalize: bool = True,
         w = w / jnp.maximum(jnp.sum(w), 1e-12)
     use_pallas = _resolve_use_pallas("flat_aggregate", use_pallas)
     if use_pallas:
-        return _flat_agg(flat, w, interpret=not _on_tpu())
+        kernel = functools.partial(_flat_agg, interpret=not _on_tpu())
+        return _on_plane_mesh(kernel, (_COLS, _WHOLE), flat.shape[1])(flat, w)
     return ref.flat_aggregate_ref(flat, w)
 
 
@@ -124,8 +168,10 @@ def client_divergence(flat, gvec, *, use_pallas: bool | None = None):
     rows)."""
     use_pallas = _resolve_use_pallas("client_divergence", use_pallas)
     if use_pallas:
-        d2 = _pairwise(flat, gvec[None, :], interpret=not _on_tpu())[:, 0]
-        return jnp.sqrt(d2)
+        kernel = functools.partial(_pairwise, interpret=not _on_tpu())
+        d2 = _on_plane_mesh(kernel, (_COLS, _COLS), flat.shape[1],
+                            psum=True)(flat, gvec[None, :])
+        return jnp.sqrt(d2[:, 0])
     diff = flat.astype(jnp.float32) - gvec.astype(jnp.float32)[None, :]
     return jnp.sqrt(jnp.sum(jnp.square(diff), axis=1))
 
@@ -164,8 +210,9 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     if use_pallas:
-        out = _flash(qt, kt, vt, causal=causal, window=window,
-                     interpret=not _on_tpu())
+        kernel = functools.partial(_flash, causal=causal, window=window,
+                                   interpret=not _on_tpu())
+        out = _on_plane_mesh(kernel, (_WHOLE,) * 3)(qt, kt, vt)
     else:
         out = ref.flash_attention_ref(qt, kt, vt, causal=causal, window=window)
     return out.transpose(0, 2, 1, 3)
@@ -188,7 +235,9 @@ def ssd(x, a, b, c, *, chunk: int = 256, n_groups: int = 1,
         af = a.transpose(0, 2, 1).reshape(B * H, S)
         bf = bh.transpose(0, 2, 1, 3).reshape(B * H, S, N)
         cf = ch.transpose(0, 2, 1, 3).reshape(B * H, S, N)
-        y, h = _ssd(xf, af, bf, cf, chunk=chunk, interpret=not _on_tpu())
+        kernel = functools.partial(_ssd, chunk=chunk,
+                                   interpret=not _on_tpu())
+        y, h = _on_plane_mesh(kernel, (_WHOLE,) * 4)(xf, af, bf, cf)
         return (y.reshape(B, H, S, P).transpose(0, 2, 1, 3),
                 h.reshape(B, H, P, N))
     return ref.ssd_ref(x, a, bh, ch)

@@ -6,10 +6,11 @@ reduce to ‖x_n − c_m‖² over clients × centroids with feature dims up to
 millions (all-weights features).
 
 TPU adaptation (DESIGN.md §5): each (bn × bf) X-tile and (bm × bf) C-tile is
-read into VMEM exactly once; the difference-square is accumulated in an fp32
-VMEM tile across the F grid axis. This avoids the ‖x‖²+‖c‖²−2x·c expansion's
-extra passes and its catastrophic cancellation in low precision. Block
-shapes default to MXU/VPU-aligned (128, 512).
+read into VMEM exactly once; within the tile the squared distance is the
+‖x‖²+‖c‖²−2x·c expansion (x·c on the MXU at f32 precision), accumulated
+in an fp32 VMEM tile across the F grid axis. Its roundoff is relative to
+‖x‖²+‖c‖², not to the distance: near-identical rows lose digits to
+cancellation. Block shapes default to MXU/VPU-aligned (128, 512).
 """
 from __future__ import annotations
 
@@ -35,7 +36,9 @@ def _pairwise_l2_kernel(x_ref, c_ref, out_ref):
     # INSIDE one slab (single read per operand, fp32 accumulate).
     xx = jnp.sum(x * x, axis=1, keepdims=True)              # [bn, 1]
     cc = jnp.sum(c * c, axis=1, keepdims=True).T            # [1, bm]
+    # HIGHEST: at Mosaic's default an f32 dot takes one bf16 MXU pass
     xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     out_ref[...] += xx + cc - 2.0 * xc
 

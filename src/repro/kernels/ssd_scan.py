@@ -11,6 +11,12 @@ lives in VMEM scratch across chunk steps.
 Layouts: X [BH, S, P]; A (log-decay, = dt·a < 0) [BH, S]; B, C [BH, S, N]
 (already head-expanded for grouped SSMs). Outputs: Y [BH, S, P] and the
 final state [BH, P, N].
+
+The wrapper hands the kernel the CHUNK-LOCAL cumulative log-decay as a
+``[BH, S, 1]`` column: Mosaic tiles the last two block dims by (8, 128)
+unless they span the whole array dim, so a ``(1, Q)`` block of a
+``[BH, S]`` array is refused (its second-to-last dim is 1, not BH), while
+``(1, Q, 1)`` is legal for any Q divisible by 8 or equal to S.
 """
 from __future__ import annotations
 
@@ -32,16 +38,17 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0].astype(jnp.float32)            # [Q, P]
-    a = a_ref[0].astype(jnp.float32)            # [Q]
+    a_cum = a_ref[0].astype(jnp.float32)        # [Q, 1] chunk-local cumsum
     b = b_ref[0].astype(jnp.float32)            # [Q, N]
     c = c_ref[0].astype(jnp.float32)            # [Q, N]
 
-    a_cum = jnp.cumsum(a)                        # [Q]
-    # intra-chunk decay matrix L[i, j] = exp(sum_{j<k<=i} a_k), i >= j
-    seg = a_cum[:, None] - a_cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(ii >= jj, jnp.exp(seg), 0.0)
+    # the same cumsum as a [1, Q] row: read the diagonal of the column
+    # broadcast (elementwise + reduce; no vector transpose of a [Q, 1])
+    a_row = jnp.sum(jnp.where(ii == jj, a_cum, 0.0), axis=0, keepdims=True)
+    # intra-chunk decay matrix L[i, j] = exp(sum_{j<k<=i} a_k), i >= j
+    L = jnp.where(ii >= jj, jnp.exp(a_cum - a_row), 0.0)
 
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [Q, Q]
@@ -52,13 +59,20 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
     # off-diagonal: carried state read out through C with in-chunk decay
     y_off = jax.lax.dot_general(c, h, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)   # [Q, P]
-    y_off = y_off * jnp.exp(a_cum)[:, None]
+    y_off = y_off * jnp.exp(a_cum)
     y_ref[0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: h' = exp(A_chunk)·h + Σ_q exp(A_chunk − a_cum_q)·x_q⊗b_q
-    decay_states = jnp.exp(a_cum[-1] - a_cum)    # [Q]
-    h_new = h * jnp.exp(a_cum[-1]) + jax.lax.dot_general(
-        x * decay_states[:, None], b, (((0,), (0,)), ((), ())),
+    a_last = a_cum[Q - 1:, :]                    # [1, 1] = A_chunk
+    decay_states = jnp.exp(a_last - a_cum)       # [Q, 1]
+    # exp(A_chunk) as a [1, N] row, read off the column like a_row:
+    # Mosaic cannot broadcast a [1, 1] across sublanes and lanes at once
+    n_state = h.shape[1]
+    last = jax.lax.broadcasted_iota(jnp.int32, (Q, n_state), 0) == Q - 1
+    chunk_decay = jnp.sum(jnp.where(last, jnp.exp(a_cum), 0.0), axis=0,
+                          keepdims=True)                          # [1, N]
+    h_new = h * chunk_decay + jax.lax.dot_general(
+        x * decay_states, b, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # [P, N]
     h_ref[...] = h_new
 
@@ -84,6 +98,10 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, interpret: bool = True):
         b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
     Sp = S + pad
+    # chunk-local cumulative log-decay, as a [BH, Sp, 1] column (see the
+    # module docstring for why the kernel never sees a [BH, Sp] block)
+    a_cum = jnp.cumsum(a.astype(jnp.float32).reshape(BH, Sp // Q, Q),
+                       axis=-1).reshape(BH, Sp, 1)
 
     kernel = functools.partial(_ssd_kernel, Q=Q)
     y, h = pl.pallas_call(
@@ -91,7 +109,7 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, interpret: bool = True):
         grid=(BH, Sp // Q),
         in_specs=[
             pl.BlockSpec((1, Q, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, Q), lambda bh, ci: (bh, ci)),
+            pl.BlockSpec((1, Q, 1), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, Q, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, Q, N), lambda bh, ci: (bh, ci, 0)),
         ],
@@ -105,5 +123,5 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, interpret: bool = True):
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, a, b, c)
+    )(x, a_cum, b, c)
     return y[:, :S], h

@@ -20,6 +20,7 @@ from repro.api import (ExperimentSpec, FleetSpec, build_cohort,
                        build_experiment, multicell_fleet_spec,
                        SELECTORS, ALLOCATORS, CHANNELS)
 from repro.core import adjusted_rand_index
+from repro.utils.compile_cache import configure_compile_cache
 
 
 def run_spec(spec: ExperimentSpec, *, checkpoint_every: int = 0,
@@ -260,6 +261,7 @@ def main(argv=None):
                     help="print the resolved ExperimentSpec JSON and exit")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.checkpoint_every < 0:
         raise SystemExit("--checkpoint-every must be >= 0")

@@ -140,10 +140,16 @@ def merge_lora(cfg: LMConfig, adapter):
 def lm_loss(adapter, batch, cfg: LMConfig):
     """Next-token cross-entropy over the window shift. ``batch["images"]``
     is ``[B, seq_len+1]`` int32; the dialect labels are partition metadata
-    only."""
+    only.
+
+    The training forward always takes the jnp attention / SSD path
+    (``use_pallas=False``): the flash-attention and SSD Pallas kernels are
+    forward-only (no VJP), and the local update differentiates this loss.
+    Evaluation (:func:`lm_evaluate`) keeps the backend's kernel route."""
     merged = merge_lora(cfg, adapter)
     tokens = batch["images"]
-    logits, _ = forward(cfg.model, merged, {"tokens": tokens[:, :-1]})
+    logits, _ = forward(cfg.model, merged, {"tokens": tokens[:, :-1]},
+                        use_pallas=False)
     targets = tokens[:, 1:]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

@@ -3,8 +3,9 @@
 import os
 import sys
 
-# persistent compile cache (pure speed-up; set before jax import)
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+from repro.utils.compile_cache import configure_compile_cache  # noqa: E402
+
+# persistent compile cache (pure speed-up; before the first compile)
+configure_compile_cache()
