@@ -11,7 +11,7 @@ flat parameter plane, dispatched as ONE scanned program.
     payload ``z`` must equal ``P_adapter * 32 / 1e6`` Mbit and sit far
     below a P_base-priced payload (the LoRA economics the subsystem
     exists for);
-  * records tokens/sec and per-phase ms to ``results/BENCH_lm.json``.
+  * records tokens/sec to ``results/BENCH_lm.json``.
 
     PYTHONPATH=src:. python benchmarks/bench_lm.py [--smoke]
 """
@@ -46,33 +46,6 @@ def _spec(model: str = "tinyllama") -> ExperimentSpec:
         selection="divergence", allocator="sao", seed=0, test_seed=92_000)
 
 
-def _best_ms(fn, repeats: int = 5):
-    fn()                                     # compile / warm
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e3
-
-
-def phase_timings(exp) -> dict:
-    """train / eval as the standalone jitted ops the traced program
-    composes (the LM-specific phases; the plane ops are workload-agnostic
-    and benchmarked by bench_round_breakdown)."""
-    S = exp.fl.devices_per_round
-    idx = np.arange(S)
-    keys = jax.random.split(jax.random.PRNGKey(0), S)
-    out = {}
-    out["train_ms"] = _best_ms(lambda: jax.block_until_ready(
-        exp.engine.train_clients(exp.global_params, exp._images[idx],
-                                 exp._labels[idx], keys)))
-    out["eval_ms"] = _best_ms(lambda: jax.block_until_ready(
-        exp.engine.evaluate(exp.global_params, exp.test_images,
-                            exp.test_labels)))
-    return out
-
-
 def run(out: str | None = None, model: str = "tinyllama") -> dict:
     spec = _spec(model)
     exp = build_experiment(spec)
@@ -103,12 +76,8 @@ def run(out: str | None = None, model: str = "tinyllama") -> dict:
     tokens = steps * BATCH * seq_len
     tok_per_sec = tokens / wall
 
-    phases = phase_timings(exp)
-
     emit(f"lm/{model}_tokens_per_sec", 1e6 / max(tok_per_sec, 1e-9),
          f"{tok_per_sec:.0f}")
-    for name, ms in phases.items():
-        emit(f"lm/{model}_{name}", ms * 1e3, f"{ms:.2f}ms")
     emit(f"lm/{model}_z_mbit", 0.0, f"{z:.4f}")
 
     payload = {
@@ -123,7 +92,6 @@ def run(out: str | None = None, model: str = "tinyllama") -> dict:
         "upload_z_base_mbit": round(z_base, 3),
         "scanned_wall_s": round(wall, 3),
         "tokens_per_sec": round(tok_per_sec, 1),
-        "phases_ms": {k: round(v, 3) for k, v in phases.items()},
         "final_accuracy": float(np.asarray(ch.accuracy)[0, -1]),
         "note": ("whole run = ONE transfer-guarded dispatch of the same "
                  "scanned round program as the CNN; per-client state is a "

@@ -1,16 +1,7 @@
-"""Per-phase breakdown of the scanned FL round on the flat parameter plane,
-plus end-to-end rounds/sec vs the recorded PR-4 scanned baseline.
-
-Phases are timed as standalone jitted ops on the real experiment state
-(the same ops the traced program composes):
-
-  train      : vmapped local SGD of the selected clients
-  eval       : test-set forward + accuracy
-  divergence : ‖w_n − w_g‖ over the [N, P] plane (ops.client_divergence)
-  aggregate  : eq.-(4) masked weighted row-reduction (ops.flat_aggregate)
-  scatter    : donated row store into the [N, P] plane
-  features   : K-means feature column slice (zero-copy)
-  sao        : one Alg.-5 spectrum solve for the selected set
+"""End-to-end rounds/sec of the scanned FL round on the flat parameter
+plane vs the recorded scanned baseline. (Where the round's time goes
+is read from a profiler trace of the program itself: the phases are named
+scopes, README "Profiling".)
 
 End-to-end rounds/sec runs the full scanned program (``FLExperiment.run``
 on the traceable bundle) on the clients=100 workload of
@@ -52,8 +43,6 @@ import numpy as np
 
 from benchmarks.common import emit, fl_spec
 from repro.api import build_experiment
-from repro.core.sao import solve_sao
-from repro.core.wireless import fleet_arrays
 from repro.kernels import ops
 
 CLIENTS = 100
@@ -81,49 +70,6 @@ def _best_ms(fn, repeats: int = 10):
         fn()
         best = min(best, time.perf_counter() - t0)
     return best * 1e3
-
-
-def phase_timings(exp) -> dict:
-    """Time each round phase as its standalone jitted op (best-of-N)."""
-    spec_cols = exp.engine.flat_spec
-    S = exp.fl.devices_per_round
-    idx = jnp.arange(S)
-    keys = jax.random.split(jax.random.PRNGKey(0), S)
-    gvec = jnp.asarray(np.asarray(exp.client_params[0]))
-    rows = exp.client_params[:S]
-    w = exp._sizes[:S]
-    arr = fleet_arrays(exp.fleet.select(np.arange(S)))
-
-    train = exp.engine.train_clients
-    ev = exp.engine.evaluate
-    div = jax.jit(lambda f, g: ops.client_divergence(f, g))
-    agg = jax.jit(lambda r, ww: ops.flat_aggregate(r, ww))
-    feat = jax.jit(lambda f: f[:, spec_cols.columns("w_fc2")] * 1.0)
-    # the production store path: DONATED in-place scatter — probe it on a
-    # private copy of the plane (donation consumes the buffer each call,
-    # so the copy threads through the timing loop)
-    scatter = jax.jit(lambda buf, i, r: buf.at[i].set(r),
-                      donate_argnums=(0,))
-    scatter_buf = [jnp.array(exp.client_params)]
-
-    def scatter_once():
-        scatter_buf[0] = scatter(scatter_buf[0], idx, rows)
-        scatter_buf[0].block_until_ready()
-
-    out = {}
-    out["train_ms"] = _best_ms(lambda: jax.block_until_ready(
-        train(exp.global_params, exp._images[idx], exp._labels[idx], keys)))
-    out["eval_ms"] = _best_ms(lambda: jax.block_until_ready(
-        ev(exp.global_params, exp.test_images, exp.test_labels)))
-    out["divergence_ms"] = _best_ms(lambda: div(
-        exp.client_params, gvec).block_until_ready())
-    out["aggregate_ms"] = _best_ms(lambda: agg(rows, w).block_until_ready())
-    out["scatter_ms"] = _best_ms(scatter_once)
-    out["features_ms"] = _best_ms(lambda: feat(
-        exp.client_params).block_until_ready())
-    out["sao_ms"] = _best_ms(lambda: solve_sao(arr, exp.B).T
-                             .block_until_ready())
-    return out
 
 
 def scanned_rps(spec, repeats: int = 3) -> float:
@@ -164,15 +110,10 @@ def recorded_baseline() -> tuple[float, str]:
 
 def run(out: str | None = None):
     spec = _workload()
-    exp = build_experiment(spec)
-    exp.run(rounds=2)                        # warm state for phase probes
-    phases = phase_timings(exp)
     rps = scanned_rps(spec)
     baseline, source = recorded_baseline()
     speedup = rps / baseline
 
-    for name, ms in phases.items():
-        emit(f"flat/{name}", ms * 1e3, f"{ms:.2f}ms")
     emit(f"flat/N{CLIENTS}_scanned_rps", 1e6 / rps, f"{rps:.2f}")
     emit(f"flat/N{CLIENTS}_speedup_vs_pr4_scanned", 0.0, f"{speedup:.2f}")
 
@@ -181,13 +122,11 @@ def run(out: str | None = None):
         "environment": {"devices": len(jax.devices()),
                         "backend": jax.default_backend(),
                         "cpu_count": os.cpu_count()},
-        "phases_ms": {k: round(v, 3) for k, v in phases.items()},
         "rounds_per_sec": round(rps, 3),
         "baseline_scanned_rps": baseline,
         "baseline_source": source,
         "speedup_vs_recorded_baseline": round(speedup, 2),
-        "note": ("phases are standalone jitted ops on real state; "
-                 "aggregation and divergence are each ONE fused op over "
+        "note": ("aggregation and divergence are each ONE fused op over "
                  "the [N, P] flat plane (ops.flat_aggregate / "
                  "ops.client_divergence) — no per-leaf tree_map remains "
                  "in the traced round body"),
@@ -351,7 +290,6 @@ def smoke(out: str | None = None) -> bool:
     verdict = "ok" if ratio >= SMOKE_MIN_RATIO else "REGRESSION"
     print(f"smoke N{CLIENTS}: flat/scanned vs recorded PR-4 baseline = "
           f"{ratio:.2f}x (floor {SMOKE_MIN_RATIO}x) ... {verdict}")
-    print(json.dumps(payload["phases_ms"], indent=1))
     return ratio >= SMOKE_MIN_RATIO
 
 

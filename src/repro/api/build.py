@@ -15,6 +15,7 @@ from repro.api.scenario import CELL_SEED_STRIDE, build_fleet
 from repro.api.spec import ExperimentSpec
 from repro.configs.base import FLConfig
 from repro.configs.paper_cnn import CNN_CONFIGS
+from repro.utils.trace import span
 
 
 #: clients at/above which a paged build keeps the data partition lazy
@@ -99,19 +100,57 @@ def build_experiment(spec: ExperimentSpec, *, cell: int = 0,
     benchmarks that probe on a train slice instead).
     """
     from repro.core.fedavg import FLExperiment       # driver (late: cycle)
-    from repro.data import (make_dataset, partition_bias,
-                            partition_bias_lazy)
-
     from repro.models.registry import model_def_for, workload_config
 
-    if spec.model in ("auto", "cnn"):
-        model_cfg = CNN_CONFIGS[spec.dataset]
-    else:
-        model_cfg = workload_config(spec.model)
-    mdef = model_def_for(model_cfg)
+    ids = dict(seed=spec.seed, cell=cell)
+    with span("build", **ids):
+        if spec.model in ("auto", "cnn"):
+            model_cfg = CNN_CONFIGS[spec.dataset]
+        else:
+            model_cfg = workload_config(spec.model)
+        mdef = model_def_for(model_cfg)
 
-    fleet, channel = fleet_for_cell(spec, cell)
-    n = fleet.num_devices
+        with span("build.fleet", **ids):
+            fleet, channel = fleet_for_cell(spec, cell)
+        n = fleet.num_devices
+
+        with span("build.data", **ids):
+            fed, test_images, test_labels = _build_data(
+                spec, model_cfg, mdef, n, cell, test_data)
+
+        with span("build.driver", **ids):
+            exp = FLExperiment(
+                model_cfg, fed, test_images, test_labels, fleet,
+                fl_config_from_spec(spec, num_devices=n),
+                bandwidth_mhz=spec.bandwidth_mhz,
+                selection=SELECTORS.resolve(spec.selection),
+                allocator=ALLOCATORS.resolve(spec.allocator),
+                aggregator=AGGREGATORS.resolve(spec.aggregator),
+                compression=COMPRESSORS.resolve(spec.compressor),
+                channel=channel,
+                seed=spec.seed,
+                batch_size=spec.batch_size,
+                fedprox_mu=spec.fedprox_mu,
+                churn=(spec.churn_leave, spec.churn_join),
+                store=spec.store,
+                k_max=spec.k_max,
+                chunk_size=spec.chunk_size,
+                div_refresh_every=spec.div_refresh_every,
+                cluster=spec.cluster,
+                p_shards=spec.p_shards,
+                faults=spec.faults,
+                quarantine_after=spec.quarantine_after)
+    exp.spec = spec
+    exp.cell = cell
+    return exp
+
+
+def _build_data(spec: ExperimentSpec, model_cfg, mdef, n: int, cell: int,
+                test_data):
+    """The experiment's training partition over ``n`` clients and its
+    held-out evaluation set (``test_data`` when given)."""
+    from repro.data import (make_dataset, partition_bias,
+                            partition_bias_lazy)
 
     if mdef.make_dataset is not None:
         # self-synthesizing workloads (the LoRA LMs) build their own
@@ -140,31 +179,7 @@ def build_experiment(spec: ExperimentSpec, *, cell: int = 0,
     fed = partition(ds, n, spec.samples_per_client, spec.sigma,
                     seed=spec.resolved_partition_seed
                     + CELL_SEED_STRIDE * cell)
-
-    exp = FLExperiment(
-        model_cfg, fed, test_images, test_labels, fleet,
-        fl_config_from_spec(spec, num_devices=n),
-        bandwidth_mhz=spec.bandwidth_mhz,
-        selection=SELECTORS.resolve(spec.selection),
-        allocator=ALLOCATORS.resolve(spec.allocator),
-        aggregator=AGGREGATORS.resolve(spec.aggregator),
-        compression=COMPRESSORS.resolve(spec.compressor),
-        channel=channel,
-        seed=spec.seed,
-        batch_size=spec.batch_size,
-        fedprox_mu=spec.fedprox_mu,
-        churn=(spec.churn_leave, spec.churn_join),
-        store=spec.store,
-        k_max=spec.k_max,
-        chunk_size=spec.chunk_size,
-        div_refresh_every=spec.div_refresh_every,
-        cluster=spec.cluster,
-        p_shards=spec.p_shards,
-        faults=spec.faults,
-        quarantine_after=spec.quarantine_after)
-    exp.spec = spec
-    exp.cell = cell
-    return exp
+    return fed, test_images, test_labels
 
 
 def build_cohort(spec: ExperimentSpec):

@@ -43,7 +43,7 @@ from jax import lax
 
 from repro.api.protocols import TracedContext
 from repro.core.engine import (EngineConfig, RoundOutputs, TracedRunResult,
-                               build_round_phases, model_eval)
+                               build_round_phases, model_eval, phase_scope)
 from repro.core.store import ClientStats
 from repro.core.wireless import completion_times, masked_max
 from repro.kernels import ops
@@ -232,22 +232,27 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
         idx = jnp.where(mask, idx, N).astype(jnp.int32)
 
         # -- dispatch: allocate, price completions, train ----------------
-        arr_sel = {k: v[idx] for k, v in arr_f.items()}
-        T, E, b, f = allocator.allocate_traced(arr_sel, ph.B, mask)
-        d = completion_times(arr_sel, b, f, mask)        # +inf on padding
+        with phase_scope("allocate"):
+            arr_sel = {k: v[idx] for k, v in arr_f.items()}
+            T, E, b, f = allocator.allocate_traced(arr_sel, ph.B, mask)
+            d = completion_times(arr_sel, b, f, mask)    # +inf on padding
         good = mask
         if faults_on:
-            state, sched, d, good = _async_fault_plan(faults, state, sched,
-                                                      idx, mask, d)
+            with phase_scope("faults"):
+                state, sched, d, good = _async_fault_plan(
+                    faults, state, sched, idx, mask, d)
         t_done = sched.t_done.at[idx].set(sched.t_now + d, mode="drop")
         state, rows = ph.train_rows(state, idx, images, labels)
         if byz_pad is not None:
-            rows = _byz_transform(faults, byz_pad, idx, state.params, rows)
+            with phase_scope("faults"):
+                rows = _byz_transform(faults, byz_pad, idx, state.params,
+                                      rows)
         # sentinel rows are out of bounds -> dropped (failed uploads are
         # re-pointed at the sentinel so a lost row never lands)
-        store_idx = idx if not faults_on else jnp.where(good, idx, N)
-        state = state._replace(
-            client_params=state.client_params.at[store_idx].set(rows))
+        with phase_scope("aggregate"):
+            store_idx = idx if not faults_on else jnp.where(good, idx, N)
+            state = state._replace(
+                client_params=state.client_params.at[store_idx].set(rows))
 
         # -- fire: the M earliest in-flight completions ------------------
         inflight = jnp.isfinite(t_done)
@@ -288,17 +293,19 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
                     bad_c.astype(jnp.float32), mode="drop"))
             w_cand = jnp.where(finite_c, w_cand, 0.0)
             ok_cand = fired_cand & finite_c
-        agg_vec, agg_opt = aggregator.aggregate_flat(
-            state.params, cand_rows, w_cand, state.opt_state)
-        # EMPTY-FIRE GUARD: flat_aggregate normalizes by max(Σw, eps), so
-        # an all-zero weight row yields a ZERO vector — an empty (or
-        # all-failed) tick must instead pass the old global (and optimizer
-        # state) through
-        any_fired = jnp.any(w_cand > 0.0) if track_faults else jnp.any(fired)
-        new_gvec = jnp.where(any_fired, agg_vec, state.params)
-        new_opt = jax.tree_util.tree_map(
-            lambda a, o: jnp.where(any_fired, a, o), agg_opt,
-            state.opt_state)
+        with phase_scope("aggregate"):
+            agg_vec, agg_opt = aggregator.aggregate_flat(
+                state.params, cand_rows, w_cand, state.opt_state)
+            # EMPTY-FIRE GUARD: flat_aggregate normalizes by max(Σw, eps),
+            # so an all-zero weight row yields a ZERO vector — an empty
+            # (or all-failed) tick must instead pass the old global (and
+            # optimizer state) through
+            any_fired = (jnp.any(w_cand > 0.0) if track_faults
+                         else jnp.any(fired))
+            new_gvec = jnp.where(any_fired, agg_vec, state.params)
+            new_opt = jax.tree_util.tree_map(
+                lambda a, o: jnp.where(any_fired, a, o), agg_opt,
+                state.opt_state)
 
         # traces read the PRE-fold ages (the staleness actually applied)
         part = jnp.sum(fired.astype(jnp.float32))
@@ -336,8 +343,10 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
         state = state._replace(params=new_gvec, opt_state=new_opt,
                                sched=sched)
 
-        acc, _ = model_eval(cfg.model_cfg)(unflatten_vector(spec, state.params),
-                                           test_images, test_labels)
+        with phase_scope("eval"):
+            acc, _ = model_eval(cfg.model_cfg)(
+                unflatten_vector(spec, state.params), test_images,
+                test_labels)
         return state, RoundOutputs(
             accuracy=acc, T=T, E=E, selected=idx, mask=mask,
             participation=part, staleness=stale, active=active)
@@ -480,13 +489,15 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
         mask and staleness-discounted weights, and the per-tick traces —
         and advances age/t_done/t_now on the stats carry."""
         sched = state.sched
-        arr_sel = {k: v[idx] for k, v in arr_f.items()}
-        T, E, b, f = allocator.allocate_traced(arr_sel, ph.B, mask)
-        d = completion_times(arr_sel, b, f, mask)        # +inf on padding
+        with phase_scope("allocate"):
+            arr_sel = {k: v[idx] for k, v in arr_f.items()}
+            T, E, b, f = allocator.allocate_traced(arr_sel, ph.B, mask)
+            d = completion_times(arr_sel, b, f, mask)    # +inf on padding
         good = mask
         if faults_on:
-            state, sched, d, good = _async_fault_plan(faults, state, sched,
-                                                      idx, mask, d)
+            with phase_scope("faults"):
+                state, sched, d, good = _async_fault_plan(
+                    faults, state, sched, idx, mask, d)
         t_done = sched.t_done.at[idx].set(sched.t_now + d, mode="drop")
         inflight = jnp.isfinite(t_done)
         order = jnp.argsort(t_done)
@@ -524,7 +535,9 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
         the dense tick: post-train, pre-staging)."""
         state, rows = ph.train_gathered(state, images_sel, labels_sel)
         if byz_pad is not None:
-            rows = _byz_transform(faults, byz_pad, idx, state.params, rows)
+            with phase_scope("faults"):
+                rows = _byz_transform(faults, byz_pad, idx, state.params,
+                                      rows)
         return state, rows
 
     def fire_fn(state, cand, cand_rows, w_cand, fired_cand, test_images,
@@ -544,20 +557,22 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
                     bad_c.astype(jnp.float32), mode="drop")))
             w_cand = jnp.where(finite_c, w_cand, 0.0)
             ok_cand = fired_cand & finite_c
-        agg_vec, agg_opt = aggregator.aggregate_flat(
-            state.params, cand_rows, w_cand, state.opt_state)
-        # EMPTY-FIRE GUARD — any(fired_cand) ≡ any(fired), see plan_fn
-        any_fired = (jnp.any(w_cand > 0.0) if track_faults
-                     else jnp.any(fired_cand))
-        new_gvec = jnp.where(any_fired, agg_vec, state.params)
-        new_opt = jax.tree_util.tree_map(
-            lambda a, o: jnp.where(any_fired, a, o), agg_opt,
-            state.opt_state)
+        with phase_scope("aggregate"):
+            agg_vec, agg_opt = aggregator.aggregate_flat(
+                state.params, cand_rows, w_cand, state.opt_state)
+            # EMPTY-FIRE GUARD — any(fired_cand) ≡ any(fired), see plan_fn
+            any_fired = (jnp.any(w_cand > 0.0) if track_faults
+                         else jnp.any(fired_cand))
+            new_gvec = jnp.where(any_fired, agg_vec, state.params)
+            new_opt = jax.tree_util.tree_map(
+                lambda a, o: jnp.where(any_fired, a, o), agg_opt,
+                state.opt_state)
         div_cand = ops.client_divergence(cand_rows, new_gvec)
         g_delta = jnp.linalg.norm(new_gvec - state.params)
         state = state._replace(params=new_gvec, opt_state=new_opt)
-        acc, _ = eval_fn(unflatten_vector(spec, new_gvec),
-                         test_images, test_labels)
+        with phase_scope("eval"):
+            acc, _ = eval_fn(unflatten_vector(spec, new_gvec),
+                             test_images, test_labels)
         return state, acc, div_cand, g_delta, ok_cand
 
     return SimpleNamespace(

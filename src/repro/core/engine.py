@@ -48,8 +48,27 @@ from repro.api.protocols import RoundState, TracedContext
 from repro.core.algorithms import make_fedprox_local_update
 from repro.kernels import ops
 from repro.models.registry import model_def_for
+from repro.utils.trace import count
 from repro.utils.trees import (StackFlattenSpec, flatten_stacked,
                                stack_flatten_spec, unflatten_vector)
+
+#: The round's phases, each a ``jax.named_scope`` named ``fl.<phase>``, so
+#: every op a phase traces carries that name in its HLO ``op_name``
+#: (backward ops as ``.../fl.train/.../transpose(jvp(...))/...``). An op's
+#: phase is the LAST ``fl.<phase>`` in its ``op_name``; ops under none are
+#: outside the phases, as are the copies the compiler adds after tracing
+#: (they carry no ``op_name``). ``compress`` nests in ``train``.
+PHASES = ("select", "allocate", "train", "compress", "faults", "aggregate",
+          "eval")
+
+
+def phase_scope(name: str):
+    """The named scope ``fl.<name>`` of one of :data:`PHASES` (a context
+    manager, or a decorator for a whole phase function). It changes op
+    metadata only, never the computation."""
+    if name not in PHASES:
+        raise ValueError(f"unknown round phase {name!r}; known: {PHASES}")
+    return jax.named_scope("fl." + name)
 
 
 @functools.lru_cache(maxsize=64)
@@ -146,14 +165,16 @@ class RoundEngine:
             local_update = make_local_update(
                 cfg.model_cfg, cfg.learning_rate, cfg.local_iters,
                 cfg.batch_size)
-        self._vmapped_update = jax.vmap(local_update, in_axes=(None, 0, 0, 0))
+        self._vmapped_update = phase_scope("train")(
+            jax.vmap(local_update, in_axes=(None, 0, 0, 0)))
         self.flat_spec = model_flat_spec(cfg.model_cfg)
         # train_clients has no input/output buffer alias to donate (its
         # output rows are param-shaped, its inputs are data-shaped); the
         # donation that stops the legacy path double-buffering the client
         # stack lives on scatter_rows, the store half of the round trip.
         self.train_clients = jax.jit(self._vmapped_update)
-        self.evaluate = jax.jit(model_eval(cfg.model_cfg))
+        self.evaluate = jax.jit(
+            phase_scope("eval")(model_eval(cfg.model_cfg)))
         # donate the global params: the new global aliases them in place
         self.round_step = jax.jit(self._round_step, donate_argnums=(0,))
         # donated in-place row scatter into the [N, P] client-weight plane
@@ -175,6 +196,7 @@ class RoundEngine:
         static hyper-parameters reuse one set of XLA executables."""
         eng = cls._CACHE.get(cfg)
         if eng is None:
+            count("program_miss", cache="engine")
             eng = cls._CACHE[cfg] = cls(cfg)
             while len(cls._CACHE) > cls._CACHE_MAX:
                 cls._CACHE.popitem(last=False)
@@ -196,11 +218,14 @@ class RoundEngine:
         pipeline, so fused host rounds and scanned rounds agree bit for
         bit)."""
         stacked = self._vmapped_update(global_params, images, labels, keys)
-        rows = flatten_stacked(stacked)
-        new_global = unflatten_vector(self.flat_spec,
-                                      ops.flat_aggregate(rows, weights))
-        acc, per_class = model_eval(self.cfg.model_cfg)(
-            new_global, test_images, test_labels)
+        with phase_scope("train"):
+            rows = flatten_stacked(stacked)
+        with phase_scope("aggregate"):
+            new_global = unflatten_vector(self.flat_spec,
+                                          ops.flat_aggregate(rows, weights))
+        with phase_scope("eval"):
+            acc, per_class = model_eval(self.cfg.model_cfg)(
+                new_global, test_images, test_labels)
         return rows, new_global, acc, per_class
 
 
@@ -352,6 +377,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
             return state._replace(channel=ch_state), arr
         return state, channel.apply_traced(k_ch, arr)
 
+    @phase_scope("train")
     def train_gathered(state, images_sel, labels_sel):
         """Local training of already-gathered ``[S_pad, ...]`` client data
         from the current global → compressed flat [S_pad, P] rows. The
@@ -371,14 +397,17 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         params = unflatten_vector(spec, state.params)
         stacked = vmapped_update(params, images_sel, labels_sel, tkeys)
         rows = flatten_stacked(stacked)                       # [S_pad, P]
-        rows = compressor.apply_flat(rows, state.params, spec)
+        with phase_scope("compress"):
+            rows = compressor.apply_flat(rows, state.params, spec)
         return state._replace(key=key), rows
 
+    @phase_scope("train")
     def train_rows(state, idx, images, labels):
         """Local training of the padded index set ``idx`` — device-side
         gathers clamp the out-of-bounds padding sentinel; masked later."""
         return train_gathered(state, images[idx], labels[idx])
 
+    @phase_scope("faults")
     def inject_faults(state, idx, mask, rows, w, d=None):
         """The traced post-train fault phase: one key split, then the
         per-dispatch drop/corrupt draws, the deterministic channel-coupled
@@ -413,6 +442,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         keep = mask & ~drop & ~corrupt
         return state._replace(key=key, sched=sched), rows, w, keep
 
+    @phase_scope("faults")
     def finite_guard(state, idx, rows, w):
         """Receive-side non-finite guard: a NaN/Inf row is zero-weighted
         out of the fold and counted as a STRIKE against its sender —
@@ -438,29 +468,31 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
                                                  d)
         if track_faults and mask is not None:
             state, w = finite_guard(state, idx, rows, w)
-        new_gvec, opt_state = aggregator.aggregate_flat(
-            state.params, rows, w, state.opt_state)
-        if faults_on and mask is not None:
-            # all-failed degradation: when every upload of the round was
-            # lost the global row and optimizer state pass through
-            # unchanged instead of folding an empty (zeroed) cohort
-            any_ok = jnp.any(w > 0.0)
-            new_gvec = jnp.where(any_ok, new_gvec, state.params)
-            opt_state = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(any_ok, new, old),
-                opt_state, state.opt_state)
-        if plane == "full":
-            # ONE scatter into the [N, P] plane; sentinel rows are out of
-            # bounds -> dropped (failed uploads are re-pointed at the
-            # sentinel so a lost/corrupted row never lands)
-            store_idx = idx
-            if faults_on and keep is not None:
-                store_idx = jnp.where(keep, idx, N)
-            new_client = state.client_params.at[store_idx].set(rows)
-        else:
-            # stats plane: the carry holds no [N, P] buffer — the caller
-            # persists rows through its ClientStore at the host boundary
-            new_client = state.client_params
+        with phase_scope("aggregate"):
+            new_gvec, opt_state = aggregator.aggregate_flat(
+                state.params, rows, w, state.opt_state)
+            if faults_on and mask is not None:
+                # all-failed degradation: when every upload of the round
+                # was lost the global row and optimizer state pass through
+                # unchanged instead of folding an empty (zeroed) cohort
+                any_ok = jnp.any(w > 0.0)
+                new_gvec = jnp.where(any_ok, new_gvec, state.params)
+                opt_state = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(any_ok, new, old),
+                    opt_state, state.opt_state)
+            if plane == "full":
+                # ONE scatter into the [N, P] plane; sentinel rows are out
+                # of bounds -> dropped (failed uploads are re-pointed at
+                # the sentinel so a lost/corrupted row never lands)
+                store_idx = idx
+                if faults_on and keep is not None:
+                    store_idx = jnp.where(keep, idx, N)
+                new_client = state.client_params.at[store_idx].set(rows)
+            else:
+                # stats plane: the carry holds no [N, P] buffer — the
+                # caller persists rows through its ClientStore at the host
+                # boundary
+                new_client = state.client_params
         return state._replace(params=new_gvec, client_params=new_client,
                               opt_state=opt_state)
 
@@ -472,20 +504,24 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         the allocation's rate; None otherwise."""
         all_idx = jnp.arange(N)
         state = train_aggregate(state, all_idx, None, images, labels, sizes)
-        feats = extract_features_flat(state.client_params, feature_layer,
-                                      spec)
-        key, sub = jax.random.split(state.key)
-        _, k_labels, _ = kmeans_fit(sub, feats, tctx.num_clusters)
+        with phase_scope("select"):
+            feats = extract_features_flat(state.client_params,
+                                          feature_layer, spec)
+            key, sub = jax.random.split(state.key)
+            _, k_labels, _ = kmeans_fit(sub, feats, tctx.num_clusters)
         state = state._replace(key=key, labels=k_labels.astype(jnp.int32))
-        acc0, _ = eval_fn(unflatten_vector(spec, state.params),
-                          test_images, test_labels)
+        with phase_scope("eval"):
+            acc0, _ = eval_fn(unflatten_vector(spec, state.params),
+                              test_images, test_labels)
         state, arr = step_channel(state, arr)
         if inr_round is not None:
             arr = dict(arr)
             arr["inr"] = arr["inr"] + inr_round
-        T0, E0, _, _ = allocator.allocate_traced(arr, B, None)
+        with phase_scope("allocate"):
+            T0, E0, _, _ = allocator.allocate_traced(arr, B, None)
         return state, (acc0, T0, E0)
 
+    @phase_scope("select")
     def select_phase(state, arr):
         """(fade →) divergence → select. The fading draw precedes
         selection so channel-aware policies (icas, rra) see the round's
@@ -522,18 +558,21 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         """allocate → train → aggregate → eval for one cell's selection.
         ``inr_round`` adds the round's selection-driven interference on top
         of any build-time ``inr`` before the solvers fold it into J."""
-        arr_sel = {k: v[idx] for k, v in arr.items()}
-        if inr_round is not None:
-            arr_sel["inr"] = arr_sel["inr"] + inr_round
-        T, E, b_sel, f_sel = allocator.allocate_traced(arr_sel, B, mask)
-        d = None
-        if faults_on and faults.deadline > 0.0:
-            # the same eq.-(5)+(8) pricing the async engine fires on: an
-            # update past the deadline is a straggler the server abandons
-            d = completion_times(arr_sel, b_sel, f_sel, mask)
+        with phase_scope("allocate"):
+            arr_sel = {k: v[idx] for k, v in arr.items()}
+            if inr_round is not None:
+                arr_sel["inr"] = arr_sel["inr"] + inr_round
+            T, E, b_sel, f_sel = allocator.allocate_traced(arr_sel, B, mask)
+            d = None
+            if faults_on and faults.deadline > 0.0:
+                # the same eq.-(5)+(8) pricing the async engine fires on:
+                # an update past the deadline is a straggler the server
+                # abandons
+                d = completion_times(arr_sel, b_sel, f_sel, mask)
         state = train_aggregate(state, idx, mask, images, labels, sizes, d)
-        acc, _ = eval_fn(unflatten_vector(spec, state.params),
-                         test_images, test_labels)
+        with phase_scope("eval"):
+            acc, _ = eval_fn(unflatten_vector(spec, state.params),
+                             test_images, test_labels)
         return state, RoundOutputs(
             accuracy=acc, T=T, E=E, selected=idx, mask=mask,
             inr=None if inr_round is None else inr_round[0])
@@ -752,6 +791,7 @@ def run_rounds(cfg: EngineConfig, *, selector, allocator, aggregator,
            quarantine_after)
     fn = _RUN_FN_CACHE.get(key)
     if fn is None:
+        count("program_miss", cache="run_rounds")
         if is_async:
             from repro.core.async_engine import _traced_async_program
             prog = _traced_async_program(

@@ -47,11 +47,17 @@ from repro.core.store import ClientStats, build_store
 from repro.core.wireless import Fleet, completion_times, fleet_arrays
 from repro.data.partition import FederatedData
 from repro.kernels.chunked import default_chunk_size, streaming_weighted_mean
+from repro.utils.trace import span
 from repro.utils.trees import (flatten_stacked, tree_flatten_vector,
                                tree_num_params, unflatten_rows,
                                unflatten_rows_np, unflatten_vector)
 
 __all__ = ["FLExperiment", "FLHistory", "RoundResult", "make_local_update"]
+
+#: the global row to the model pytree in ONE device dispatch: a scanned
+#: run's carry comes back after the device has finished, where a slice and
+#: a reshape per leaf, op by op, would each add a dispatch to the run
+_unflatten_vector_jit = jax.jit(unflatten_vector, static_argnums=0)
 
 
 @dataclass
@@ -177,6 +183,7 @@ class FLExperiment:
         self.fleet = fleet
         self.fl = fl
         self.B = bandwidth_mhz
+        self.seed = seed                 # the id of this run's trace spans
         self.rng = np.random.default_rng(seed)
         self.key = jax.random.PRNGKey(seed)
         self.test_images = jnp.asarray(test_images)
@@ -911,9 +918,10 @@ class FLExperiment:
             hist.selected.append(all_idx)
         churn_on = self.churn != (0.0, 0.0)
         for k in range(rounds):
-            if churn_on:
-                self._churn_step_host()
-            res = self.round(method)
+            with span("round", seed=self.seed, round=k):
+                if churn_on:
+                    self._churn_step_host()
+                res = self.round(method)
             hist.append(res)
             if ck is not None:
                 ck.maybe(k, hist)
@@ -973,65 +981,66 @@ class FLExperiment:
         state = self.traced_state()
         state = prog.init_channel(state, arr)
         for k in range(rounds):
-            if needs_div:
-                # serve selection from the refreshed stats table — the
-                # paged replacement for the dense full-plane reduction
-                div = self._paged_divergences()
-                state = state._replace(sched=state.sched._replace(
-                    divergence=jnp.asarray(div)))
-            state, arr_f, idx, mask = prog.sched(state, arr)
-            idx_h = np.asarray(idx)
-            mask_h = np.asarray(mask)
-            # the host-side mirror of the device gather's clamped OOB
-            # sentinel: padding lanes read client N-1's data, train, and
-            # are dropped by the mask — identical PRNG consumption
-            idx_c = np.minimum(idx_h, n - 1)
-            images_sel = self._client_images(idx_c)
-            labels_sel = self._labels[jnp.asarray(idx_c)]
-            state, T, E, cand, fired_cand, w_cand, good, traces = prog.plan(
-                state, arr_f, idx, mask, self._sizes)
-            state, rows = prog.train(state, idx, images_sel, labels_sel)
-            live = idx_h[mask_h]
-            # persist the GOOD lanes only (== mask when fault-free): a
-            # dropped/corrupted dispatch never reaches the store, exactly
-            # like the dense tick's sentinel scatter
-            good_h = np.asarray(good)
-            stored = idx_h[good_h]
-            if stored.size:
-                store.stage(stored,
-                            rows[jnp.asarray(np.flatnonzero(good_h))])
-            cand_h = np.asarray(cand)
-            cand_rows = store.gather_staged(cand_h)
-            state, acc, div_cand, g_delta, ok_cand = prog.fire(
-                state, cand, cand_rows, w_cand, fired_cand,
-                self.test_images, self.test_labels)
-            fired_h = np.asarray(fired_cand)
-            fired_ids = cand_h[fired_h]
-            store.release_staged(fired_ids)
-            # stats-table upkeep, the per-tick version of the sync loop's
-            # _finish_paged_round: every stale bound grows by this fold's
-            # global step (exactly 0 on an empty fire); fired clients get
-            # their exact refreshed divergence back from the fold —
-            # except lanes the non-finite guard rejected (ok_cand=False),
-            # whose divergence entry must not turn NaN
-            stats.drift[store.touched] += float(g_delta)
-            ok_h = np.asarray(ok_cand)
-            ok_ids = cand_h[ok_h]
-            if ok_ids.size:
-                stats.divergence[ok_ids] = np.asarray(div_cand)[ok_h]
-                stats.drift[ok_ids] = 0.0
-            self._gvec_host = np.asarray(state.params)
-            self._rounds_since_refresh = min(
-                self._rounds_since_refresh + 1, np.iinfo(np.int32).max - 1)
-            part, stale, active = traces
-            acc = float(acc)
-            hist.accuracy.append(acc)
-            hist.T_k.append(float(T))
-            hist.E_k.append(float(E))
-            hist.selected.append(live)
-            hist.participation.append(float(part))
-            hist.staleness.append(float(stale))
-            hist.active.append(float(active))
+            with span("round", seed=self.seed, round=k):
+                if needs_div:
+                    # serve selection from the refreshed stats table — the
+                    # paged replacement for the dense full-plane reduction
+                    div = self._paged_divergences()
+                    state = state._replace(sched=state.sched._replace(
+                        divergence=jnp.asarray(div)))
+                state, arr_f, idx, mask = prog.sched(state, arr)
+                idx_h = np.asarray(idx)
+                mask_h = np.asarray(mask)
+                # the host-side mirror of the device gather's clamped OOB
+                # sentinel: padding lanes read client N-1's data, train, and
+                # are dropped by the mask — identical PRNG consumption
+                idx_c = np.minimum(idx_h, n - 1)
+                images_sel = self._client_images(idx_c)
+                labels_sel = self._labels[jnp.asarray(idx_c)]
+                (state, T, E, cand, fired_cand, w_cand, good,
+                 traces) = prog.plan(state, arr_f, idx, mask, self._sizes)
+                state, rows = prog.train(state, idx, images_sel, labels_sel)
+                live = idx_h[mask_h]
+                # persist the GOOD lanes only (== mask when fault-free): a
+                # dropped/corrupted dispatch never reaches the store, exactly
+                # like the dense tick's sentinel scatter
+                good_h = np.asarray(good)
+                stored = idx_h[good_h]
+                if stored.size:
+                    store.stage(stored,
+                                rows[jnp.asarray(np.flatnonzero(good_h))])
+                cand_h = np.asarray(cand)
+                cand_rows = store.gather_staged(cand_h)
+                state, acc, div_cand, g_delta, ok_cand = prog.fire(
+                    state, cand, cand_rows, w_cand, fired_cand,
+                    self.test_images, self.test_labels)
+                fired_h = np.asarray(fired_cand)
+                fired_ids = cand_h[fired_h]
+                store.release_staged(fired_ids)
+                # stats-table upkeep, the per-tick version of the sync loop's
+                # _finish_paged_round: every stale bound grows by this fold's
+                # global step (exactly 0 on an empty fire); fired clients get
+                # their exact refreshed divergence back from the fold —
+                # except lanes the non-finite guard rejected (ok_cand=False),
+                # whose divergence entry must not turn NaN
+                stats.drift[store.touched] += float(g_delta)
+                ok_h = np.asarray(ok_cand)
+                ok_ids = cand_h[ok_h]
+                if ok_ids.size:
+                    stats.divergence[ok_ids] = np.asarray(div_cand)[ok_h]
+                    stats.drift[ok_ids] = 0.0
+                self._gvec_host = np.asarray(state.params)
+                self._rounds_since_refresh = min(
+                    self._rounds_since_refresh + 1, np.iinfo(np.int32).max - 1)
+                part, stale, active = traces
+                acc = float(acc)
+                hist.accuracy.append(acc)
+                hist.T_k.append(float(T))
+                hist.E_k.append(float(E))
+                hist.selected.append(live)
+                hist.participation.append(float(part))
+                hist.staleness.append(float(stale))
+                hist.active.append(float(active))
             if ck is not None and ck.due(k):
                 # fold the carry into the host tables (read-only on the
                 # device state), snapshot, keep driving the same carry
@@ -1273,7 +1282,7 @@ class FLExperiment:
         """Sync a (final) scan carry back into the host driver, so a traced
         run can be inspected or continued by the Python loop."""
         spec = self.engine.flat_spec
-        self.global_params = unflatten_vector(spec, state.params)
+        self.global_params = _unflatten_vector_jit(spec, state.params)
         if self._store.kind == "dense":
             self.client_params = state.client_params
         self.key = state.key
@@ -1290,6 +1299,27 @@ class FLExperiment:
 
     def _run_traced(self, selector, rounds: int,
                     include_initial_round: bool) -> FLHistory:
+        """The scanned program in three host spans under ``repro.run``:
+        ``launch`` (program lookup, carry, placement, the asynchronous
+        call), ``wait`` (the device's execution) and ``fetch`` (the carry
+        back into the driver, the history to the host)."""
+        with span("run", seed=self.seed):
+            with span("run.launch", seed=self.seed):
+                with_init, res = self._launch_traced(selector, rounds,
+                                                     include_initial_round)
+            with span("run.wait", seed=self.seed):
+                jax.block_until_ready(res)
+            with span("run.fetch", seed=self.seed):
+                self.load_traced_state(res.state,
+                                       clusters_valid=with_init
+                                       or self.cluster_labels is not None)
+                return self.history_from_traced(res, with_init,
+                                                self.fed.num_clients)
+
+    def _launch_traced(self, selector, rounds: int,
+                       include_initial_round: bool):
+        """Dispatch the scanned program; returns ``(with_init, result)``
+        with the result still computing on the device."""
         with_init = include_initial_round or self.clusters is None
         fn = run_rounds(self.engine.cfg, selector=selector,
                         allocator=self.allocator, aggregator=self.aggregator,
@@ -1320,11 +1350,7 @@ class FLExperiment:
             res = fn(state, self._images, self._labels,
                      self._sizes, fleet_arrays(self.fleet),
                      self.test_images, self.test_labels)
-        self.load_traced_state(res.state,
-                               clusters_valid=with_init
-                               or self.cluster_labels is not None)
-        return self.history_from_traced(res, with_init,
-                                        self.fed.num_clients)
+        return with_init, res
 
     @staticmethod
     def history_from_traced(res: TracedRunResult, with_init: bool,

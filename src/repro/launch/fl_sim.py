@@ -14,6 +14,7 @@ import argparse
 import json
 import sys
 
+import jax
 import numpy as np
 
 from repro.api import (ExperimentSpec, FleetSpec, build_cohort,
@@ -260,9 +261,21 @@ def main(argv=None):
     ap.add_argument("--dump-spec", action="store_true",
                     help="print the resolved ExperimentSpec JSON and exit")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a JAX profiler trace of the run to DIR: "
+                         "device ops named by round phase (fl.*), host "
+                         "spans repro.* (README 'Profiling')")
     args = ap.parse_args(argv)
     configure_compile_cache()
+    if args.profile:
+        with jax.profiler.trace(args.profile):
+            _run_args(args)
+    else:
+        _run_args(args)
 
+
+def _run_args(args):
+    """Run what the parsed command line asks for."""
     if args.checkpoint_every < 0:
         raise SystemExit("--checkpoint-every must be >= 0")
     if args.checkpoint_every and not (args.checkpoint_dir or args.resume):
