@@ -443,10 +443,9 @@ def cohort_phase(devs) -> None:
               f"shape {tuple(arr.shape)}: (device, lanes) "
               f"{[(d, f'{lo}:{hi}') for d, lo, hi in held]}")
 
-    # the same 8 lanes vmapped on one chip. All 8 in one program need
-    # 36.8 GB of HBM at these widths (a compile refusal on v5e), so the
-    # lanes run as four 2-lane vmapped dispatches — each the very program
-    # one chip of the sharded run executes
+    # the same 8 lanes vmapped on one chip, as four 2-lane vmapped
+    # dispatches — each the very program one chip of the sharded run
+    # executes
     real_mesh = cohort.cohort_mesh
     cohort.cohort_mesh = lambda n: None
     ref = []
