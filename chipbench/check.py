@@ -1,14 +1,16 @@
 """The comparison that decides ``correct``: one experiment of the window,
 drawn from the run's seed, against the plain reference.
 
-Numbers (those in ``ORDER`` are compared, each with its limit from
-``limits/<cell>.json``; ``param_gap`` and ``kmeans_gap`` are recorded by
-``readings.py`` only):
+Numbers (a cell compares those that ``limits/<cell>.json`` gives a limit,
+in ``ORDER``, and ``ALWAYS`` in every cell; ``readings.py`` records them
+all):
 
 ``inputs_mismatch``
-    Elements of the experiment's inputs (client images, labels and
-    sizes, test set, fleet arrays, model widths and FL settings) that
-    differ from what ``generate`` says the seed generates. Exact: 0.
+    Elements of the experiment's inputs (client samples, labels and
+    sizes, test set, fleet arrays, the frozen base where the model has
+    one, model widths and FL settings) that differ from what ``generate``
+    and the reference's ``frozen`` say the seed and the configuration
+    give. Exact: 0.
 ``param_gap``
     The reference replays the experiment on the run's own choices
     (selections, lane layout, clusters). By the worst leaf, over the final
@@ -29,7 +31,8 @@ Numbers (those in ``ORDER`` are compared, each with its limit from
     near 0.
 ``kmeans_gap``
     The run's clusters judged as a K-means (Lloyd) solution on the
-    reference's features (the w_fc2 columns after the initial round):
+    reference's features (the model's ``features`` after the initial
+    round):
     over clients, the largest excess of the squared distance to the mean
     of its own cluster over that to the nearest cluster mean, as a share
     of the former. 0 at a Lloyd fixed point, whichever seeding reached it.
@@ -42,22 +45,35 @@ Numbers (those in ``ORDER`` are compared, each with its limit from
 """
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from chipbench import generate
 from chipbench.reference import fl
 
-#: the numbers compared with a limit; ``param_gap`` and ``kmeans_gap`` are
-#: recorded by ``readings.py`` but not compared: on the chip no control or
-#: fault reads them far enough above sound runs (PERF.md section 2)
-ORDER = ("inputs_mismatch", "acc_mean_gap", "select_gap", "T_gap", "E_gap",
-         "window_compile_s")
+#: the numbers a cell may compare, in the order they print. Which ones a
+#: cell compares is its limits file's to say: a number whose two readings
+#: no limit can separate in that cell has none there (``mnist.paper``
+#: compares neither ``param_gap`` nor ``kmeans_gap``: on the chip no
+#: control or fault reads them far enough above sound runs, PERF.md
+#: section 2)
+ORDER = ("inputs_mismatch", "param_gap", "acc_mean_gap", "select_gap",
+         "kmeans_gap", "T_gap", "E_gap", "window_compile_s")
+
+#: compared in every cell, exactly
+ALWAYS = ("inputs_mismatch", "window_compile_s")
 
 #: the rounds after the initial one whose picks ``select_gap`` judges by
 #: the reference's divergences: later, the summation order's last bits
 #: carry the reference's weights, and so its divergences, too far from the
 #: run's for a shortfall to tell a fault (PERF.md section 2)
 SELECT_ROUNDS = 1
+
+#: a frozen leaf of more elements than this is compared by an exact digest
+#: taken on the device, not copied to the host (a published LoRA base
+#: holds gigabytes)
+DIGEST_MIN = 1 << 26
 
 
 def _mismatch(a, b) -> int:
@@ -82,21 +98,78 @@ def lanes_from_selection(selected, labels, c: int, N: int):
     return out, bad
 
 
-def input_mismatches(prog: dict, gen: dict, model_cfg: dict,
-                     spec: dict) -> dict:
-    """Per input, elements that differ from the generator's."""
+@jax.jit
+def _digest(x):
+    """Two wrapping uint32 sums over the leaf's bits, each element
+    weighted by its position: equal arrays give equal digests on any
+    summation order, and a changed element or a moved one changes them."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = jax.lax.bitcast_convert_type(
+            x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+    bits = x.reshape(-1).astype(jnp.uint32)
+    i = jnp.arange(bits.size, dtype=jnp.uint32)
+    return jnp.stack([jnp.sum(bits * (i * np.uint32(2654435761) + 1)),
+                      jnp.sum(bits ^ (i * np.uint32(40503) + 7))])
+
+
+def frozen_view(tree) -> dict:
+    """A frozen tree by leaf path: small leaves as host arrays, large ones
+    (over ``DIGEST_MIN`` elements) as ``(shape, dtype, digest)``; None
+    where the model has no frozen tree."""
+    if tree is None:
+        return None
+    out = {}
+    for name, leaf in fl.paths(tree):
+        if leaf.size > DIGEST_MIN:
+            out[name] = (tuple(leaf.shape), str(leaf.dtype),
+                         tuple(int(d) for d in np.asarray(_digest(leaf))))
+        else:
+            out[name] = np.asarray(leaf)
+    return out
+
+
+def _frozen_mismatch(run: dict, want: dict) -> int:
+    """Elements of the frozen tree that differ; a leaf compared by digest,
+    or found on one side only, counts whole."""
+    if run is None or want is None:
+        return 0 if run is None and want is None else 1
+    count = 0
+    for name in set(run) | set(want):
+        a, b = run.get(name), want.get(name)
+        if isinstance(a, np.ndarray) and isinstance(b, np.ndarray):
+            count += _mismatch(a, b)
+        elif not (isinstance(a, tuple) and a == b):
+            v = a if a is not None else b
+            count += max(int(np.prod(v[0])) if isinstance(v, tuple)
+                         else v.size, 1)
+    return count
+
+
+def width(model_cfg: dict, dotted: str):
+    """The configuration's value of a dotted width (``model.d_model``)."""
+    v = model_cfg
+    for part in dotted.split("."):
+        v = v[part]
+    return v
+
+
+def input_mismatches(prog: dict, gen: dict, model_cfg: dict, spec: dict,
+                     frozen: dict = None) -> dict:
+    """Per input, elements that differ from the generator's; ``frozen`` is
+    the reference's frozen tree in ``frozen_view`` form."""
     pin = prog["inputs"]
     counts = {
-        "images": _mismatch(pin["images"], gen["images"]),
-        "labels": _mismatch(pin["labels"], gen["labels"]),
+        "x": _mismatch(pin["x"], gen["x"]),
+        "y": _mismatch(pin["y"], gen["y"]),
         "sizes": _mismatch(pin["sizes"], gen["sizes"]),
-        "test_images": _mismatch(pin["test_images"], gen["test_images"]),
-        "test_labels": _mismatch(pin["test_labels"], gen["test_labels"]),
+        "test_x": _mismatch(pin["test_x"], gen["test_x"]),
+        "test_y": _mismatch(pin["test_y"], gen["test_y"]),
         "fleet": sum(_mismatch(pin["fleet"][k],
                                np.asarray(gen["fleet"][k], np.float32))
                      for k in gen["fleet"]),
-        "model": sum(int(pin["model"][k] != model_cfg[k])
-                     for k in pin["model"]),
+        "frozen": _frozen_mismatch(pin["frozen"], frozen),
+        "widths": sum(int(pin["widths"].get(k) != width(model_cfg, k))
+                      for k in model_cfg["widths"]),
         "settings": sum(int(pin["settings"][k] != spec[k])
                         for k in pin["settings"]),
     }
@@ -104,8 +177,8 @@ def input_mismatches(prog: dict, gen: dict, model_cfg: dict,
 
 
 def leaf_gaps(prog: dict, ref: dict) -> dict:
-    """Per leaf (``w_c1`` .. ``b_fc2``), the largest relative gap over the
-    global model and every client row: ``|w_run - w_ref|`` over the
+    """Per leaf (by path, ``fl.leaves_by_path``), the largest relative gap
+    over the global model and every client row: ``|w_run - w_ref|`` over the
     reference's move of that leaf from the initial weights, or over the
     median leaf's move in the same row where that is larger (a leaf that
     barely moves is judged on the row's scale, not on its own)."""
@@ -184,15 +257,16 @@ def rel_gap(a, b) -> float:
 def numbers(prog: dict, model_cfg: dict, spec: dict, *,
             reference: fl.Experiment = None, gen: dict = None) -> dict:
     """Every compared number for one experiment's outputs ``prog`` (see
-    ``harness.program_outputs``). Builds the inputs from the seed and runs
+    ``run.program_outputs``). Builds the inputs from the seed and runs
     the reference unless given."""
     N = spec["clients"]
     if gen is None:
         gen = generate.experiment(prog["seed"], spec, model_cfg)
-    mism = input_mismatches(prog, gen, model_cfg, spec) \
-        if "inputs" in prog else {}
     if reference is None:
         reference = fl.Experiment(model_cfg, spec)
+    mism = (input_mismatches(prog, gen, model_cfg, spec,
+                             frozen_view(reference.frozen))
+            if "inputs" in prog else {})
     ref = reference.run(gen, forced={"lanes": prog["lanes"],
                                      "labels": prog["labels"]})
     acc = np.asarray(prog["accuracy"], np.float64)
@@ -217,11 +291,13 @@ def numbers(prog: dict, model_cfg: dict, spec: dict, *,
 
 
 def verdict(values: dict, limits: dict):
-    """``(correct, [(name, value, limit)])`` in the fixed order; a number
-    that is not finite, or has no limit, fails."""
+    """``(correct, [(name, value, limit)])`` in the fixed order, over the
+    numbers that the cell's ``limits`` name and those of ``ALWAYS``; a
+    number that is not finite, or has no limit, fails."""
     rows, ok = [], True
     for name in ORDER:
-        if name not in values:
+        if name not in values or (name not in limits
+                                  and name not in ALWAYS):
             continue
         v, lim = values[name], limits.get(name)
         good = lim is not None and np.isfinite(v) and v <= lim

@@ -1,10 +1,11 @@
 """Operations and bytes the benchmark's work needs, from shapes alone.
 
-Model FLOPs count the multiply-adds of the convolutions and dense layers
-(2 FLOPs each); a training sample costs its forward and backward pass,
-3x the forward. Biases, ReLU, pooling, recomputation and padding are not
-counted. Kernel bytes and FLOPs are those of one call at its logical
-(unpadded) operand shapes, in float32.
+Model FLOPs per training and per test sample come from the configuration's
+reference module (``train_flops``, ``eval_flops``; each states its rule:
+for the paper's CNN the multiply-adds of the convolutions and dense
+layers, 2 FLOPs each, and 3x the forward for a training sample).
+Recomputation and padding are never counted. Kernel bytes and FLOPs are
+those of one call at its logical (unpadded) operand shapes, in float32.
 """
 from __future__ import annotations
 
@@ -25,28 +26,17 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def cnn_forward_flops(cfg: dict) -> int:
-    """FLOPs of one sample's forward pass through the paper's CNN."""
-    h, w = cfg["input_hw"]
-    k, p, cin = cfg["kernel"], cfg["pool"], cfg["input_channels"]
-    c1, c2, f1, nc = (cfg["conv1_out"], cfg["conv2_out"], cfg["fc1_out"],
-                      cfg["num_classes"])
-    h1, w1 = h - k + 1, w - k + 1
-    conv1 = h1 * w1 * c1 * k * k * cin
-    h2, w2 = h1 // p - k + 1, w1 // p - k + 1
-    conv2 = h2 * w2 * c2 * k * k * c1
-    flat = (h2 // p) * (w2 // p) * c2
-    return 2 * (conv1 + conv2 + flat * f1 + f1 * nc)
-
-
 def experiment_flops(cfg: dict, spec: dict, updates: int) -> int:
     """Model FLOPs of one experiment with ``updates`` client local updates
     (the initial round's N and each round's selected clients): L SGD steps
-    of ``batch_size`` samples each, forward and backward, plus a forward
-    pass over the test set after the initial round and after each round."""
-    fwd = cnn_forward_flops(cfg)
-    train = updates * spec["local_iters"] * spec["batch_size"] * 3 * fwd
-    evals = (spec["rounds"] + 1) * spec["test_samples"] * fwd
+    of ``batch_size`` training samples each, plus the test set after the
+    initial round and after each round."""
+    from chipbench.reference import fl
+
+    m = fl.model_module(cfg["reference"])
+    train = (updates * spec["local_iters"] * spec["batch_size"]
+             * m.train_flops(cfg))
+    evals = (spec["rounds"] + 1) * spec["test_samples"] * m.eval_flops(cfg)
     return train + evals
 
 

@@ -139,7 +139,7 @@ def main(argv=None, *, require_tpu: bool = True, overrides=None) -> int:
                 else:
                     gen = None
                     unit = runner.run(seed)
-                    prog = bench.program_outputs(unit, spec)
+                    prog = bench.program_outputs(unit, spec, model)
                     del unit
                 t1 = time.perf_counter()
                 res = check.numbers(prog, model, spec, reference=ref,

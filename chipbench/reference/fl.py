@@ -20,12 +20,37 @@ Two modes:
   made the run choose otherwise than the reference would.
 * free: the reference makes every choice itself (the control).
 
-Arithmetic: the model in ``dtype`` at matmul ``precision`` (see
-``paper_cnn``), by default the precision the configuration states
-(``precision.matmul``: the program's convolutions and dense layers run at
-XLA's default, one bf16 pass on a TPU, so the reference does too; at
-HIGHEST, 31 rounds of SGD turn that one-pass rounding into a 3-9% gap in
-the final weights, as wide as the bfloat16 control's); aggregation,
+The model is a module of its own (``reference/<name>.py``, named by the
+configuration's ``reference`` key) that gives the model interface:
+
+``init(cfg, key)``
+    the trainable tree (one client's model, as the program's plane holds
+    it), drawn from the experiment's key;
+``frozen(cfg)``
+    the frozen tree that every client shares, made from the
+    configuration alone (a LoRA base from its ``base_seed``), or None;
+``loss(params, x, y, cfg, precision, frozen=None)``
+    the local training loss on one minibatch;
+``evaluate(params, x, y, cfg, precision, frozen=None)``
+    the test accuracy, a scalar;
+``features(clients)``
+    Alg. 2's K-means input, ``[N, F]``, from the stacked client trees;
+``make_data(cfg, spec, num, seed)``
+    ``(x, y, classes)``: ``num`` samples and their classes (the
+    partition's labels), from ``seed``;
+``upload_mbit(cfg)``
+    the upload payload z of one client, in Mbit;
+``train_flops(cfg)``, ``eval_flops(cfg)``
+    model FLOPs of one training sample and one test sample;
+``as_input(x, dtype)``
+    the host array ``x`` as the model takes it, for weights in ``dtype``.
+
+Arithmetic: the model (trainable and frozen trees) in ``dtype`` at matmul
+``precision``, by default the precision the configuration states
+(``precision.matmul``: where the program runs its contractions at XLA's
+default, one bf16 pass on a TPU, the reference does too; for the paper's
+CNN at HIGHEST, 31 rounds of SGD turn that one-pass rounding into a 3-9%
+gap in the final weights, as wide as the bfloat16 control's); aggregation,
 divergences and K-means in ``dtype`` elementwise; the allocation in NumPy
 at ``alloc_dtype`` (float64 for the reference; ``ml_dtypes.bfloat16`` for
 the control).
@@ -48,7 +73,23 @@ PRECISIONS = {"default": jax.lax.Precision.DEFAULT,
 
 
 def model_module(name: str):
+    """The model module ``chipbench/reference/<name>.py``."""
     return importlib.import_module(f"chipbench.reference.{name}")
+
+
+def paths(tree) -> list:
+    """A tree's ``(path, leaf)`` pairs in flatten order, each path its keys
+    joined by ``/`` (a flat dict keeps its keys; a nested adapter's leaf
+    reads ``blocks/mamba/in_proj_a``): the names the program's flat plane
+    gives its leaves."""
+    return [("/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                      for k in path), leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def leaves_by_path(tree, dtype=None) -> dict:
+    """A tree's leaves as host arrays keyed by their path (``paths``)."""
+    return {name: np.asarray(leaf, dtype) for name, leaf in paths(tree)}
 
 
 class Experiment:
@@ -64,32 +105,35 @@ class Experiment:
         self.precision = precision
         self.alloc_dtype = alloc_dtype
         self.m = model_module(model_cfg["reference"])
+        base = self.m.frozen(model_cfg)
+        self.frozen = None if base is None else jax.tree_util.tree_map(
+            lambda x: x.astype(dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating) else x, base)
         cfg, L, bs, lr = (model_cfg, spec["local_iters"], spec["batch_size"],
                           spec["learning_rate"])
         m, prec = self.m, precision
 
-        def local(params, images, labels, key):
+        def local(params, frozen, x, y, key):
             def step(p, k):
-                idx = jax.random.randint(k, (bs,), 0, images.shape[0])
-                g = jax.grad(m.loss)(p, images[idx], labels[idx], cfg, prec)
+                idx = jax.random.randint(k, (bs,), 0, x.shape[0])
+                g = jax.grad(m.loss)(p, x[idx], y[idx], cfg, prec, frozen)
                 return jax.tree_util.tree_map(
                     lambda w, gw: (w - jnp.asarray(lr, w.dtype) * gw)
                     .astype(w.dtype), p, g), None
             p, _ = jax.lax.scan(step, params, jax.random.split(key, L))
             return p
 
-        def train(params, images, labels, keys, weights):
-            rows = jax.vmap(local, in_axes=(None, 0, 0, 0))(
-                params, images, labels, keys)
+        def train(params, frozen, x, y, keys, weights):
+            rows = jax.vmap(local, in_axes=(None, None, 0, 0, 0))(
+                params, frozen, x, y, keys)
             w = (weights / jnp.sum(weights)).astype(dtype)
             new = jax.tree_util.tree_map(
                 lambda r: jnp.sum(w.reshape((-1,) + (1,) * (r.ndim - 1)) * r,
                                   0).astype(dtype), rows)
             return rows, new
 
-        def evaluate(params, x, y):
-            logits = m.forward(params, x, cfg, prec)
-            return jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+        def evaluate(params, frozen, x, y):
+            return m.evaluate(params, x, y, cfg, prec, frozen)
 
         def divergence(clients, g):
             sq = jax.tree_util.tree_map(
@@ -110,8 +154,9 @@ class Experiment:
         ``forced``: ``{"lanes": [R, S_pad] int (N = empty lane),
         "labels": [N]}``. Returns the history (accuracy, T, E per round,
         initial round first), the lane layout and labels used, the final
-        global and client weights (float32 NumPy trees), the initial
-        weights, and, per round, the reference's divergences."""
+        global and client weights and the initial weights (float32 host
+        arrays by leaf path, ``leaves_by_path``), and, per round, the
+        reference's divergences."""
         spec, dt = self.spec, self.dtype
         N = spec["clients"]
         c, s = spec["num_clusters"], spec["selected_per_cluster"]
@@ -123,24 +168,25 @@ class Experiment:
         key, sub = jax.random.split(key)
         w0 = self.m.init(self.cfg, sub)
         g = jax.tree_util.tree_map(lambda x: x.astype(dt), w0)
-        images = jnp.asarray(inputs["images"], dt)
-        labels = jnp.asarray(inputs["labels"])
+        base = self.frozen
+        x = self.m.as_input(inputs["x"], dt)
+        y = jnp.asarray(inputs["y"])
         sizes = np.asarray(inputs["sizes"], np.float32)
-        tx = jnp.asarray(inputs["test_images"], dt)
-        ty = jnp.asarray(inputs["test_labels"])
+        tx = self.m.as_input(inputs["test_x"], dt)
+        ty = jnp.asarray(inputs["test_y"])
         fleet = inputs["fleet"]
 
         # Alg. 2 initial round: every client trains from the initial model
         key, sub = jax.random.split(key)
-        clients, g = self._train(g, images, labels,
+        clients, g = self._train(g, base, x, y,
                                  jax.random.split(sub, N),
                                  jnp.asarray(sizes))
         key, sub = jax.random.split(key)
-        feats = clients["w_fc2"].reshape(N, -1)
+        feats = self.m.features(clients)
         use_labels = (np.asarray(self._kmeans(sub, feats)) if forced is None
                       else np.asarray(forced["labels"]))
         T0, E0 = allocate(fleet, np.arange(N), B, self.alloc_dtype)
-        acc = [float(self._eval(g, tx, ty))]
+        acc = [float(self._eval(g, base, tx, ty))]
         Ts, Es = [T0], [E0]
         lanes_all, divs = [], []
         for k in range(spec["rounds"]):
@@ -157,12 +203,12 @@ class Experiment:
             Es.append(E)
             key, sub = jax.random.split(key)
             keys = jax.random.split(sub, lanes.shape[0])[valid]
-            rows, g = self._train(g, images[sel], labels[sel], keys,
+            rows, g = self._train(g, base, x[sel], y[sel], keys,
                                   jnp.asarray(sizes[sel]))
             clients = jax.tree_util.tree_map(
                 lambda cl, r: cl.at[sel].set(r), clients, rows)
-            acc.append(float(self._eval(g, tx, ty)))
-        as_np = lambda t: {n: np.asarray(v, np.float32) for n, v in t.items()}
+            acc.append(float(self._eval(g, base, tx, ty)))
+        as_np = lambda t: leaves_by_path(t, np.float32)
         return {"accuracy": np.asarray(acc), "T": np.asarray(Ts),
                 "E": np.asarray(Es), "lanes": np.stack(lanes_all),
                 "labels": use_labels, "features": np.asarray(feats, np.float64),

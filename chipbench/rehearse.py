@@ -4,15 +4,17 @@ machine without one, and print its ``memory_analysis``.
 
     JAX_PLATFORMS=cpu python chipbench/rehearse.py [workload ...]
 
-Each cell compiles for one chip of a described ``v5e:2x2``. The kernel seams are steered onto the Mosaic
-kernels (``interpret=False``) as on a TPU backend. Nothing runs: the
-compile shows whether the program fits a chip and holds its kernels, not
-how fast it is. Prints one JSON line per cell.
+Each cell compiles for one chip of a described ``v5e:2x2``. The kernel
+seams are steered onto the Mosaic kernels (``interpret=False``) as on a
+TPU backend. Nothing runs: the compile shows whether the program fits a
+chip and which Mosaic kernels it holds, not how fast it is. Prints one
+JSON line per cell.
 """
 from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -59,9 +61,8 @@ def main(argv=None) -> int:
             test_shared=True, mesh=None, channel=exp.channel)
         t0 = time.perf_counter()
         lowered = fn.lower(*sds)
-        kernels = sorted({k for k in ("_flat_aggregate_kernel",
-                                      "_pairwise_l2_kernel")
-                          if f'kernel_name = "{k}"' in lowered.as_text()})
+        kernels = sorted(set(re.findall(r'kernel_name = "(\w+)"',
+                                        lowered.as_text())))
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         print(json.dumps({

@@ -7,7 +7,14 @@
 A cell (``BENCHMARK.json`` ``workloads``) is a model configuration
 (``chipbench/configs/<config>.json``) under a traffic mix
 (``chipbench/traffic/<traffic>.json``, the ``ExperimentSpec`` fields of one
-experiment). The run
+experiment). The configuration names its plain reference
+(``chipbench/reference/<reference>.py``, the model interface of
+``reference/fl.py``), may add ``ExperimentSpec`` fields under ``spec``
+(``{"model": "mamba2-130m"}``; a ``dataset`` key sets that field), lists
+under ``widths`` the dotted attributes of the program's model config that
+must equal its own values (``model.d_model``, ``rank``), and may name
+under ``program_frozen`` the program's function ``module:name`` that
+gives the frozen tree for the model config. The run
 
 1. refuses to start without a TPU, or with fewer chips than the cell asks;
 2. set-up: imports, backend start, one whole experiment (build and run)
@@ -16,13 +23,14 @@ experiment). The run
 3. window: whole experiments back to back, each on its own seed drawn from
    ``--seed``, until ``--seconds`` have passed; the last one runs to its
    end. Each calls ``build_experiment(spec)`` then ``FLExperiment.run``.
-   Compiles inside the window are counted;
+   Compiles inside the window are counted. Of each experiment the window
+   keeps its counts; the experiment itself it keeps for one alone, drawn
+   from the seed as it goes (``Window``);
 4. with ``--trace 1`` the window runs under the JAX profiler and the
    per-layer metrics (``chipbench/metrics/<metric>.py``) read the trace
    and the benchmark's own host spans;
-5. check: one experiment of the window, drawn from the seed, is compared
-   with the plain reference (``chipbench/check.py``), after the program's
-   state is freed.
+5. check: the kept experiment is compared with the plain reference
+   (``chipbench/check.py``), after the program's state is freed.
 
 The last stdout line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and
@@ -86,9 +94,13 @@ def load_cell(name: str) -> dict:
 
 
 def spec_dict(cell: dict, overrides: dict = None) -> dict:
-    """The experiment's settings: the traffic's spec on the config's
-    dataset (``overrides`` shrink it for tests on the CPU)."""
-    spec = dict(cell["traffic"]["spec"], dataset=cell["model"]["dataset"])
+    """The experiment's settings: the traffic's spec with the
+    configuration's ``dataset`` and ``spec`` fields (``overrides`` shrink
+    it for tests on the CPU)."""
+    spec = dict(cell["traffic"]["spec"])
+    if "dataset" in cell["model"]:
+        spec["dataset"] = cell["model"]["dataset"]
+    spec.update(cell["model"].get("spec", {}))
     spec.update(overrides or {})
     return spec
 
@@ -173,22 +185,61 @@ class Runner:
                                t_end=time.perf_counter())
 
 
-def program_outputs(unit, spec: dict) -> dict:
+class Window:
+    """The experiments of the timed window. Of each it keeps the seed, the
+    count of client updates and the end time; the experiment itself it
+    keeps for one alone, the one the check reads. The k-th experiment
+    replaces the kept one with probability 1/k (a reservoir draw from
+    ``default_rng([seed, 1])``), so the checked experiment is uniform over
+    the window and fixed by the seed, and every other one is freed before
+    the next is built: at most two are alive at once."""
+
+    def __init__(self, seed: int):
+        import numpy as np
+
+        self.rng = np.random.default_rng([int(seed), 1])
+        self.units = []
+        self.kept = None
+
+    def add(self, unit) -> None:
+        self.units.append(SimpleNamespace(
+            seed=unit.seed, updates=unit.updates, t_end=unit.t_end))
+        if self.rng.integers(len(self.units)) == 0:
+            self.kept = unit
+
+
+def program_attr(dotted: str):
+    """The object a ``module:name`` string names."""
+    module, name = dotted.split(":")
+    return getattr(importlib.import_module(module), name)
+
+
+def program_width(model_cfg, dotted: str):
+    """A dotted attribute of the program's model config, a tuple as a
+    list (as the configuration file writes it)."""
+    v = model_cfg
+    for part in dotted.split("."):
+        v = getattr(v, part)
+    return list(v) if isinstance(v, tuple) else v
+
+
+def program_outputs(unit, spec: dict, model_cfg: dict) -> dict:
     """What the sampled experiment produced and consumed, on the host."""
     import numpy as np
     from repro.core.wireless import fleet_arrays
 
     from chipbench import check
+    from chipbench.reference import fl
 
     exp, hist = unit.exp, unit.hist
     N, c = spec["clients"], spec["num_clusters"]
     labels = np.asarray(exp.cluster_labels)
     lanes, bad = check.lanes_from_selection(hist.selected[1:], labels, c, N)
-    mc = exp.model_cfg
-    model = {k: getattr(mc, k) for k in (
-        "input_channels", "conv1_out", "conv2_out", "fc1_out",
-        "num_classes", "kernel", "pool")}
-    model["input_hw"] = list(mc.input_hw)
+    widths = {k: program_width(exp.model_cfg, k)
+              for k in model_cfg["widths"]}
+    frozen = (check.frozen_view(
+        program_attr(model_cfg["program_frozen"])(exp.model_cfg))
+        if "program_frozen" in model_cfg else None)
     settings = {
         "clients": int(exp.fed.num_clients),
         "samples_per_client": int(exp.fed.images.shape[1]),
@@ -209,16 +260,16 @@ def program_outputs(unit, spec: dict) -> dict:
         "accuracy": np.asarray(hist.accuracy), "T": np.asarray(hist.T_k),
         "E": np.asarray(hist.E_k), "lanes": lanes, "lanes_bad": bad,
         "labels": labels,
-        "global": {k: np.asarray(v) for k, v in exp.global_params.items()},
-        "clients": exp.client_tree(),
+        "global": fl.leaves_by_path(exp.global_params),
+        "clients": fl.leaves_by_path(exp.client_tree()),
         "inputs": {
-            "images": exp.fed.images, "labels": exp.fed.labels,
+            "x": exp.fed.images, "y": exp.fed.labels,
             "sizes": exp.fed.sizes,
-            "test_images": np.asarray(exp.test_images),
-            "test_labels": np.asarray(exp.test_labels),
+            "test_x": np.asarray(exp.test_images),
+            "test_y": np.asarray(exp.test_labels),
             "fleet": {k: np.asarray(v)
                       for k, v in fleet_arrays(exp.fleet).items()},
-            "model": model, "settings": settings},
+            "frozen": frozen, "widths": widths, "settings": settings},
     }
 
 
@@ -296,8 +347,6 @@ def main(argv=None, *, require_tpu: bool = True, overrides: dict = None,
               file=sys.stderr)
         return 3
     devs = devs[:chips]
-    import numpy as np
-
     from chipbench import check, generate, trace
     from chipbench.reference import fl
 
@@ -318,19 +367,20 @@ def main(argv=None, *, require_tpu: bool = True, overrides: dict = None,
     if logdir:
         jax.profiler.start_trace(logdir)
     spans.items.clear()
-    done = []
+    window = Window(args.seed)
+    units = window.units
     t0 = time.perf_counter()
     with clock.measure(), spans("window"):
         while True:
-            done.append(runner.run(seeds[len(done) + 1]))
-            if done[-1].t_end - t0 >= args.seconds:
+            window.add(runner.run(seeds[len(units) + 1]))
+            if units[-1].t_end - t0 >= args.seconds:
                 break
     window_compile_s = clock.seconds
-    elapsed = done[-1].t_end - t0
+    elapsed = units[-1].t_end - t0
     if logdir:
         jax.profiler.stop_trace()
-    updates = sum(u.updates for u in done)
-    attempted = len(done)
+    updates = sum(u.updates for u in units)
+    attempted = len(units)
     device = device_info(devs)
 
     metrics, breakdown = {}, None
@@ -346,7 +396,7 @@ def main(argv=None, *, require_tpu: bool = True, overrides: dict = None,
 
         ctx = SimpleNamespace(
             red=red, spans=[s for s in spans.items if s[0] != "window"],
-            units=done, model=cell["model"], spec=spec, chips=chips,
+            units=units, model=cell["model"], spec=spec, chips=chips,
             peaks=flops.peaks(
                 devs[0].device_kind) if require_tpu else None)
         for m in cell["per_layer"]:
@@ -365,11 +415,9 @@ def main(argv=None, *, require_tpu: bool = True, overrides: dict = None,
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
 
-    # ---- check one experiment of the window, drawn from the seed
-    rng = np.random.default_rng([args.seed, 1])
-    unit = done[int(rng.integers(len(done)))]
-    prog = program_outputs(unit, spec)
-    del done, unit, runner
+    # ---- check the one experiment the window kept
+    prog = program_outputs(window.kept, spec, cell["model"])
+    del window, runner
     gc.collect()
     ref = fl.Experiment(cell["model"], spec)
     res = check.numbers(prog, cell["model"], spec, reference=ref)

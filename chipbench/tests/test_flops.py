@@ -5,7 +5,8 @@ import os
 import pytest
 
 from chipbench import flops, run
-from chipbench.reference import paper_cnn
+from chipbench.reference import lora_lm, paper_cnn
+from test_new_model import CONFIG as LORA_SMOKE
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -24,13 +25,15 @@ def test_mnist_cnn_forward_flops_by_hand():
     # conv1 24x24x15 outputs of 5x5x1; conv2 8x8x28 of 5x5x15;
     # fc1 448->224; fc2 224->10; two FLOPs per multiply-add
     macs = 24 * 24 * 15 * 25 + 8 * 8 * 28 * 25 * 15 + 448 * 224 + 224 * 10
-    assert flops.cnn_forward_flops(cfg("mnist_cnn")) == 2 * macs == 1_981_184
+    assert paper_cnn.forward_flops(cfg("mnist_cnn")) == 2 * macs == 1_981_184
+    assert paper_cnn.train_flops(cfg("mnist_cnn")) == 3 * 2 * macs
+    assert paper_cnn.eval_flops(cfg("mnist_cnn")) == 2 * macs
 
 
 def test_cifar10_cnn_forward_flops_by_hand():
     # conv1 28x28x15 of 5x5x3; conv2 10x10x28 of 5x5x15; fc1 700->300
     macs = (28 * 28 * 15 * 75 + 10 * 10 * 28 * 375 + 700 * 300 + 300 * 10)
-    assert flops.cnn_forward_flops(cfg("cifar10_cnn")) == 2 * macs \
+    assert paper_cnn.forward_flops(cfg("cifar10_cnn")) == 2 * macs \
         == 4_290_000
 
 
@@ -47,6 +50,24 @@ def test_experiment_flops_by_hand():
     fwd = 1_981_184
     want = 340 * 20 * 32 * 3 * fwd + 31 * 1000 * fwd
     assert flops.experiment_flops(cfg("mnist_cnn"), spec, 340) == want
+
+
+def test_lora_lm_flops_by_hand():
+    """The Mamba-2 smoke base (d 128, d_inner 256, 8 heads of 32, state
+    16, conv width 4, vocab 256, 2 layers) with rank-4 adapters, per
+    32-token window."""
+    in_w = 2 * 256 + 2 * 16 + 8                  # z, x B C, dt
+    in_proj, out_proj = 2 * 128 * in_w, 2 * 256 * 128
+    conv, ssd = 2 * 4 * (256 + 32), 4 * 8 * 32 * 16
+    adapters = 2 * 4 * (128 + in_w) + 2 * 4 * (256 + 128)
+    head = 2 * 128 * 256
+    fwd = 2 * (in_proj + conv + out_proj + ssd + adapters) + head
+    bwd = (2 * (in_proj + conv + out_proj + 2 * ssd + 2 * adapters) + head
+           - 2 * 128 * (in_w + 4))              # layer 0 input: no grad
+    assert lora_lm.eval_flops(LORA_SMOKE) == 32 * fwd == 17_076_224
+    assert lora_lm.train_flops(LORA_SMOKE) == 32 * (fwd + bwd)
+    assert lora_lm.upload_mbit(LORA_SMOKE) == 2 * 4 * (
+        128 + in_w + 256 + 128) * 32 / 1e6
 
 
 P_PAD = 114_176        # 113,744 columns in 512-column blocks

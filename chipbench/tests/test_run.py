@@ -8,6 +8,8 @@ import os
 import shutil
 import subprocess
 import sys
+import weakref
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -17,10 +19,11 @@ from conftest import FAULT_SIZE, ROOT, TINY
 from chipbench import check, faults, generate, run
 
 
-def run_cell(workload="mnist.paper", seed=3_000_000_001, overrides=TINY):
+def run_cell(workload="mnist.paper", seed=3_000_000_001, overrides=TINY,
+             seconds=0.5):
     out = io.StringIO()
     rc = run.main(["--workload", workload, "--seed", str(seed),
-                   "--seconds", "0.5", "--trace", "0"],
+                   "--seconds", str(seconds), "--trace", "0"],
                   require_tpu=False, overrides=overrides, out=out)
     assert rc == 0
     return json.loads(out.getvalue().strip().splitlines()[-1])
@@ -31,6 +34,42 @@ def test_honest_run_is_correct():
     assert res["correct"] is True, res["checks"]
     assert list(res)[-1] == "checks"
     assert set(res["metrics"]) == {"updates_per_s", "setup_s"}
+
+
+def test_the_window_keeps_at_most_two_experiments_alive(monkeypatch):
+    """Each experiment of the window but the kept one is freed before the
+    next is built (weak references to every experiment built)."""
+    refs, before, after = [], [], []
+    real = run.Runner.run
+
+    def tracked(self, seed):
+        before.append(sum(r() is not None for r in refs))
+        unit = real(self, seed)
+        refs.append(weakref.ref(unit.exp))
+        after.append(sum(r() is not None for r in refs))
+        return unit
+
+    monkeypatch.setattr(run.Runner, "run", tracked)
+    res = run_cell(seconds=3.0)
+    assert res["correct"] is True, res["checks"]
+    assert res["attempted"] >= 3
+    assert max(before) <= 1 and max(after) <= 2, (before, after)
+
+
+def test_the_checked_experiment_is_drawn_uniformly_by_the_seed():
+    """The reservoir draw: one seed keeps the same experiment of the same
+    window every time; over seeds each of five is kept about as often."""
+    def kept(seed, n=5):
+        w = run.Window(seed)
+        for k in range(n):
+            w.add(SimpleNamespace(seed=k, updates=1, t_end=float(k),
+                                  exp=k))
+        assert [u.seed for u in w.units] == list(range(n))
+        return w.kept.exp
+
+    assert [kept(2 ** 40 + 9) for _ in range(3)] == [kept(2 ** 40 + 9)] * 3
+    counts = np.bincount([kept(s) for s in range(2000)], minlength=5)
+    assert counts.min() > 330 and counts.max() < 470, counts
 
 
 def test_a_wrong_small_leaf_shows_in_param_gap():
@@ -124,3 +163,28 @@ def test_benchmark_files_alone_print_no_result(tmp_path):
     p = _cli(tmp_path)
     assert p.returncode != 0
     assert p.stdout == ""
+
+
+@pytest.mark.parametrize("digest_min", [check.DIGEST_MIN, 0])
+def test_a_frozen_base_is_compared_element_for_element_or_by_digest(
+        monkeypatch, digest_min):
+    """A frozen leaf small enough is compared on the host element by
+    element; a larger one by its digest on the device, where one changed
+    or moved element counts the leaf whole."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(check, "DIGEST_MIN", digest_min)
+    base = {"embed": jnp.arange(12.0).reshape(3, 4),
+            "blocks": {"w": jnp.ones((2, 5), jnp.bfloat16)}}
+    want = check.frozen_view(base)
+    assert check._frozen_mismatch(check.frozen_view(base), want) == 0
+    one = dict(base, embed=base["embed"].at[1, 2].add(1e-3))
+    moved = dict(base, embed=base["embed"][::-1])
+    by_digest = digest_min == 0
+    assert check._frozen_mismatch(check.frozen_view(one), want) == (
+        12 if by_digest else 1)
+    # the middle row stays in place: on the host, 8 elements differ
+    assert check._frozen_mismatch(check.frozen_view(moved), want) == (
+        12 if by_digest else 8)
+    assert check._frozen_mismatch(None, want) == 1
+    assert check._frozen_mismatch(None, None) == 0
