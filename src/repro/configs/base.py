@@ -51,8 +51,11 @@ class ModelConfig:
     norm_eps: float = 1e-5
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (jamba): one attention layer per `attn_period` layers, rest SSM.
+    # hybrid (jamba, granite-4.0-h): one attention layer per `attn_period`
+    # layers, rest SSM; `attn_index` is its position inside the period
+    # (-1 = last, jamba's layout; granite-4.0-h puts it at 5 of 10).
     attn_period: int = 0            # 0 = not hybrid
+    attn_index: int = -1
     moe_period: int = 0             # MoE MLP every `moe_period` layers (0 = per `moe` on all)
     # encoder-decoder (seamless): num_layers applies to each stack.
     is_encoder_decoder: bool = False
@@ -63,6 +66,16 @@ class ModelConfig:
     # audio: encoder consumes pre-extracted frame embeddings (stub frontend).
     continuous_encoder_input: bool = False
     max_seq_len: int = 1 << 20
+    # granite-style scalings (1.0 / None = off): the embedding output is
+    # scaled by `embedding_multiplier`, each mixer's and MLP's output by
+    # `residual_multiplier` before its residual add, attention scores by
+    # `attention_multiplier` (None = 1/sqrt(head_dim)), and the logits
+    # divided by `logits_scaling`. `rope=False` is NoPE (no rotary).
+    rope: bool = True
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     source: str = ""                # citation for the assigned config
 
     @property
@@ -79,6 +92,14 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    def is_attention_layer(self, layer_idx: int) -> bool:
+        """Whether layer ``layer_idx`` mixes with attention (else SSM)."""
+        if self.family == "ssm":
+            return False
+        if not self.attn_period:
+            return True
+        return layer_idx % self.attn_period == self.attn_index % self.attn_period
 
     # ---- analytic parameter counts (for roofline MODEL_FLOPS = 6·N·D) ----
     def _attn_params(self) -> int:
@@ -116,10 +137,7 @@ class ModelConfig:
 
         def block_params(layer_idx: int, decoder: bool) -> int:
             p = per_layer_norms
-            is_attn = True
-            if self.attn_period:
-                is_attn = (layer_idx % self.attn_period) == (self.attn_period - 1)
-            if self.family == "ssm" or (self.attn_period and not is_attn):
+            if not self.is_attention_layer(layer_idx):
                 p += self._ssm_params()
             else:
                 p += self._attn_params()

@@ -10,6 +10,7 @@ sees the same weight-divergence signal; SAO the same payloads).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -22,12 +23,17 @@ from repro.utils.trees import tree_sub, tree_scale, tree_add
 def make_fedprox_local_update(model_cfg, lr: float,
                               local_iters: int, batch_size: int,
                               mu: float = 0.01):
-    """FedProx client update: SGD on  f_n(w) + μ/2‖w − w_g‖²."""
+    """FedProx client update: SGD on  f_n(w) + μ/2‖w − w_g‖²; ``frozen``
+    (the workload's frozen tree, ``engine.frozen_params``) goes to the
+    loss, as in ``engine.make_local_update``."""
     loss_fn = model_def_for(model_cfg).loss
 
-    def local_update(global_params, images, labels, key):
+    def local_update(global_params, images, labels, key, frozen=None):
+        loss = (loss_fn if frozen is None
+                else functools.partial(loss_fn, frozen=frozen))
+
         def prox_loss(p, batch):
-            base = loss_fn(p, batch, model_cfg)
+            base = loss(p, batch, model_cfg)
             sq = sum(jnp.sum(jnp.square(a.astype(jnp.float32)
                                         - b.astype(jnp.float32)))
                      for a, b in zip(jax.tree_util.tree_leaves(p),

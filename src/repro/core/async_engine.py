@@ -43,7 +43,8 @@ from jax import lax
 
 from repro.api.protocols import TracedContext
 from repro.core.engine import (EngineConfig, RoundOutputs, TracedRunResult,
-                               build_round_phases, model_eval, phase_scope)
+                               build_round_phases, frozen_kw, model_eval,
+                               phase_scope)
 from repro.core.store import ClientStats
 from repro.core.wireless import completion_times, masked_max
 from repro.kernels import ops
@@ -209,7 +210,8 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
             age=jnp.where(avail, sched.age, 0.0))
         return state._replace(key=key, sched=sched)
 
-    def tick(state, images, labels, sizes, arr, test_images, test_labels):
+    def tick(state, images, labels, sizes, arr, test_images, test_labels,
+             frozen):
         if churn_on:
             state = churn_step(state)
         sched = state.sched
@@ -242,7 +244,7 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
                 state, sched, d, good = _async_fault_plan(
                     faults, state, sched, idx, mask, d)
         t_done = sched.t_done.at[idx].set(sched.t_now + d, mode="drop")
-        state, rows = ph.train_rows(state, idx, images, labels)
+        state, rows = ph.train_rows(state, idx, images, labels, frozen)
         if byz_pad is not None:
             with phase_scope("faults"):
                 rows = _byz_transform(faults, byz_pad, idx, state.params,
@@ -346,20 +348,20 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
         with phase_scope("eval"):
             acc, _ = model_eval(cfg.model_cfg)(
                 unflatten_vector(spec, state.params), test_images,
-                test_labels)
+                test_labels, **frozen_kw(frozen))
         return state, RoundOutputs(
             accuracy=acc, T=T, E=E, selected=idx, mask=mask,
             participation=part, staleness=stale, active=active)
 
     def sync_tick(state, images, labels, sizes, arr, test_images,
-                  test_labels):
+                  test_labels, frozen):
         """The degenerate branch: the synchronous round body verbatim,
         with the async traces welded on (staleness identically zero, the
         whole fleet active)."""
         state, arr_f, idx, mask = ph.select_phase(state, arr)
         state, outs = ph.finish_phase(state, arr_f, idx, mask, None, images,
                                       labels, sizes, test_images,
-                                      test_labels)
+                                      test_labels, frozen)
         return state, outs._replace(
             participation=jnp.sum(mask.astype(jnp.float32)),
             staleness=jnp.zeros((), jnp.float32),
@@ -368,7 +370,7 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
     body = sync_tick if degenerate else tick
 
     def run(state, images, labels, sizes, arr, test_images, test_labels,
-            rounds: int, with_init: bool):
+            frozen=None, *, rounds: int, with_init: bool):
         arr = dict(arr)
         arr.pop("xgain", None)           # single-cell: no cross gains
         state = ph.init_channel(state, arr)
@@ -379,11 +381,11 @@ def _traced_async_program(cfg: EngineConfig, selector, allocator,
         if with_init:
             state, init_out = ph.init_round(state, images, labels, sizes,
                                             arr, None, test_images,
-                                            test_labels)
+                                            test_labels, frozen)
 
         def step(s, _):
             return body(s, images, labels, sizes, arr, test_images,
-                        test_labels)
+                        test_labels, frozen)
 
         state, outs = lax.scan(step, state, None, length=rounds)
         if init_out is None:
@@ -528,12 +530,12 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
         return (state, T, E, cand, fired_cand, w_cand, good,
                 (part, stale, active))
 
-    def train_fn(state, idx, images_sel, labels_sel):
+    def train_fn(state, idx, images_sel, labels_sel, frozen):
         """O(K·P) local SGD of the host-gathered cohort data — the same
         ``train_gathered`` closure (and key split) as every other driver.
         ``idx`` only feeds the byzantine row transform (same placement as
         the dense tick: post-train, pre-staging)."""
-        state, rows = ph.train_gathered(state, images_sel, labels_sel)
+        state, rows = ph.train_gathered(state, images_sel, labels_sel, frozen)
         if byz_pad is not None:
             with phase_scope("faults"):
                 rows = _byz_transform(faults, byz_pad, idx, state.params,
@@ -541,7 +543,7 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
         return state, rows
 
     def fire_fn(state, cand, cand_rows, w_cand, fired_cand, test_images,
-                test_labels):
+                test_labels, frozen):
         """Fold the M candidate rows (staged back from the store), guard
         the empty fire, evaluate; returns the fired candidates' refreshed
         divergence, the global step norm ‖g_new − g_old‖ (exactly 0 on an
@@ -572,7 +574,7 @@ def _paged_async_step_program(cfg: EngineConfig, selector, allocator,
         state = state._replace(params=new_gvec, opt_state=new_opt)
         with phase_scope("eval"):
             acc, _ = eval_fn(unflatten_vector(spec, new_gvec),
-                             test_images, test_labels)
+                             test_images, test_labels, **frozen_kw(frozen))
         return state, acc, div_cand, g_delta, ok_cand
 
     return SimpleNamespace(

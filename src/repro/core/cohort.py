@@ -260,13 +260,14 @@ class CohortRunner:
                         test_shared=test_shared, mesh=mesh,
                         channel=e0.channel, cells=prog_cells,
                         churn=getattr(e0, "churn", (0.0, 0.0)))
+        frozen = e0.engine.frozen
         if transfer_guard:
             with jax.transfer_guard_device_to_host("disallow"):
                 res: TracedRunResult = fn(state, images, labels, sizes, arr,
-                                          test_images, test_labels)
+                                          test_images, test_labels, frozen)
         else:
             res = fn(state, images, labels, sizes, arr,
-                     test_images, test_labels)
+                     test_images, test_labels, frozen)
 
         # sync each real lane's final state back into its host experiment
         # (pad lanes are dropped)

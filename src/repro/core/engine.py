@@ -48,7 +48,7 @@ from repro.api.protocols import RoundState, TracedContext
 from repro.core.algorithms import make_fedprox_local_update
 from repro.kernels import ops
 from repro.models.registry import model_def_for
-from repro.utils.trace import count
+from repro.utils.trace import count, span
 from repro.utils.trees import (StackFlattenSpec, flatten_stacked,
                                stack_flatten_spec, unflatten_vector)
 
@@ -85,20 +85,47 @@ def model_flat_spec(model_cfg) -> StackFlattenSpec:
     return stack_flatten_spec(template)
 
 
+@functools.lru_cache(maxsize=4)
+def frozen_params(model_cfg):
+    """``model_cfg``'s frozen tree on the device (the :class:`ModelDef`'s
+    ``frozen`` hook; None where the workload has none), placed once per
+    process and config, in the span ``repro.build.frozen`` and counted by
+    ``repro.count.frozen_upload``. Every program takes it as an argument
+    (``frozen``), so no compiled program holds it as a constant."""
+    mdef = model_def_for(model_cfg)
+    if mdef.frozen is None:
+        return None
+    name = getattr(model_cfg, "name", type(model_cfg).__name__)
+    with span("build.frozen", config=name):
+        tree = jax.block_until_ready(jax.device_put(mdef.frozen(model_cfg)))
+    count("frozen_upload", config=name)
+    return tree
+
+
+def frozen_kw(frozen) -> dict:
+    """The keyword a model hook takes its frozen tree by: none at all for
+    a workload without one, so its traced program is unchanged."""
+    return {} if frozen is None else {"frozen": frozen}
+
+
 def make_local_update(model_cfg, lr: float, local_iters: int,
                       batch_size: int):
     """One client's local training: L SGD steps on its own shard (Alg. 1
     lines 6-10, with the paper-endorsed SGD variant of §III-A). The loss
     comes from ``model_cfg``'s registered :class:`ModelDef` — for
     ``CNNConfig`` it IS the original ``cnn_loss`` function object, so the
-    traced jaxpr is bit-identical to the pre-registry engine."""
+    traced jaxpr is bit-identical to the pre-registry engine. ``frozen``
+    (the workload's frozen tree, ``frozen_params``) goes to the loss."""
     loss_fn = model_def_for(model_cfg).loss
 
-    def local_update(params, images, labels, key):
+    def local_update(params, images, labels, key, frozen=None):
+        loss = (loss_fn if frozen is None
+                else functools.partial(loss_fn, frozen=frozen))
+
         def step(p, k):
             idx = jax.random.randint(k, (batch_size,), 0, images.shape[0])
             batch = {"images": images[idx], "labels": labels[idx]}
-            g = jax.grad(loss_fn)(p, batch, model_cfg)
+            g = jax.grad(loss)(p, batch, model_cfg)
             p = jax.tree_util.tree_map(lambda w, gw: w - lr * gw, p, g)
             return p, None
 
@@ -111,8 +138,9 @@ def make_local_update(model_cfg, lr: float, local_iters: int,
 
 @functools.lru_cache(maxsize=64)
 def model_eval(model_cfg):
-    """``(params, test_x, test_y) -> (accuracy, per_class)`` for
-    ``model_cfg``'s workload (cached so every program traces one closure)."""
+    """``(params, test_x, test_y, **frozen_kw(frozen)) -> (accuracy,
+    per_class)`` for ``model_cfg``'s workload (cached so every program
+    traces one closure)."""
     mdef = model_def_for(model_cfg)
     return functools.partial(mdef.evaluate, cfg=model_cfg)
 
@@ -166,17 +194,21 @@ class RoundEngine:
                 cfg.model_cfg, cfg.learning_rate, cfg.local_iters,
                 cfg.batch_size)
         self._vmapped_update = phase_scope("train")(
-            jax.vmap(local_update, in_axes=(None, 0, 0, 0)))
+            jax.vmap(local_update, in_axes=(None, 0, 0, 0, None)))
         self.flat_spec = model_flat_spec(cfg.model_cfg)
+        #: the workload's frozen tree on the device (None for the CNN),
+        #: the last argument of every program below
+        self.frozen = frozen_params(cfg.model_cfg)
         # train_clients has no input/output buffer alias to donate (its
         # output rows are param-shaped, its inputs are data-shaped); the
         # donation that stops the legacy path double-buffering the client
         # stack lives on scatter_rows, the store half of the round trip.
-        self.train_clients = jax.jit(self._vmapped_update)
-        self.evaluate = jax.jit(
-            phase_scope("eval")(model_eval(cfg.model_cfg)))
+        self._train_clients = jax.jit(self._vmapped_update)
+        self._evaluate = jax.jit(phase_scope("eval")(
+            lambda params, x, y, frozen: model_eval(cfg.model_cfg)(
+                params, x, y, **frozen_kw(frozen))))
         # donate the global params: the new global aliases them in place
-        self.round_step = jax.jit(self._round_step, donate_argnums=(0,))
+        self._round_step = jax.jit(self._round_step_fn, donate_argnums=(0,))
         # donated in-place row scatter into the [N, P] client-weight plane
         self.scatter_rows = jax.jit(
             lambda buf, idx, rows: buf.at[idx].set(rows),
@@ -207,9 +239,23 @@ class RoundEngine:
     def init_params(self, key):
         return model_def_for(self.cfg.model_cfg).init(self.cfg.model_cfg, key)
 
+    def train_clients(self, params, images, labels, keys):
+        """The vmapped local update of one client per row of ``images``."""
+        return self._train_clients(params, images, labels, keys, self.frozen)
+
+    def evaluate(self, params, test_images, test_labels):
+        """``(accuracy, per_class)`` of ``params`` on the test set."""
+        return self._evaluate(params, test_images, test_labels, self.frozen)
+
+    def round_step(self, global_params, images, labels, keys, weights,
+                   test_images, test_labels):
+        """The fused round (``_round_step_fn``); donates ``global_params``."""
+        return self._round_step(global_params, images, labels, keys, weights,
+                                test_images, test_labels, self.frozen)
+
     # -- fused fast path -----------------------------------------------
-    def _round_step(self, global_params, images, labels, keys, weights,
-                    test_images, test_labels):
+    def _round_step_fn(self, global_params, images, labels, keys, weights,
+                       test_images, test_labels, frozen):
         """Train the selected clients, aggregate (eq. 4), evaluate.
 
         Returns the clients' post-training models as flat ``[S, P]`` rows
@@ -217,7 +263,8 @@ class RoundEngine:
         ``ops.flat_aggregate`` row-reduction (same numerics as the traced
         pipeline, so fused host rounds and scanned rounds agree bit for
         bit)."""
-        stacked = self._vmapped_update(global_params, images, labels, keys)
+        stacked = self._vmapped_update(global_params, images, labels, keys,
+                                       frozen)
         with phase_scope("train"):
             rows = flatten_stacked(stacked)
         with phase_scope("aggregate"):
@@ -225,7 +272,7 @@ class RoundEngine:
                                           ops.flat_aggregate(rows, weights))
         with phase_scope("eval"):
             acc, per_class = model_eval(self.cfg.model_cfg)(
-                new_global, test_images, test_labels)
+                new_global, test_images, test_labels, **frozen_kw(frozen))
         return rows, new_global, acc, per_class
 
 
@@ -332,7 +379,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
     else:
         local_update = make_local_update(
             cfg.model_cfg, cfg.learning_rate, cfg.local_iters, cfg.batch_size)
-    vmapped_update = jax.vmap(local_update, in_axes=(None, 0, 0, 0))
+    vmapped_update = jax.vmap(local_update, in_axes=(None, 0, 0, 0, None))
     spec = model_flat_spec(cfg.model_cfg)
     eval_fn = model_eval(cfg.model_cfg)
     N, B = tctx.num_devices, tctx.bandwidth_mhz
@@ -378,7 +425,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         return state, channel.apply_traced(k_ch, arr)
 
     @phase_scope("train")
-    def train_gathered(state, images_sel, labels_sel):
+    def train_gathered(state, images_sel, labels_sel, frozen=None):
         """Local training of already-gathered ``[S_pad, ...]`` client data
         from the current global → compressed flat [S_pad, P] rows. The
         store-agnostic core: the dense path gathers by index on device
@@ -395,17 +442,18 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         # wants named leaves, so unflatten the global row for the vmapped
         # SGD steps and flatten the results straight back onto the plane
         params = unflatten_vector(spec, state.params)
-        stacked = vmapped_update(params, images_sel, labels_sel, tkeys)
+        stacked = vmapped_update(params, images_sel, labels_sel, tkeys,
+                                 frozen)
         rows = flatten_stacked(stacked)                       # [S_pad, P]
         with phase_scope("compress"):
             rows = compressor.apply_flat(rows, state.params, spec)
         return state._replace(key=key), rows
 
     @phase_scope("train")
-    def train_rows(state, idx, images, labels):
+    def train_rows(state, idx, images, labels, frozen=None):
         """Local training of the padded index set ``idx`` — device-side
         gathers clamp the out-of-bounds padding sentinel; masked later."""
-        return train_gathered(state, images[idx], labels[idx])
+        return train_gathered(state, images[idx], labels[idx], frozen)
 
     @phase_scope("faults")
     def inject_faults(state, idx, mask, rows, w, d=None):
@@ -454,11 +502,12 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
                 bad.astype(jnp.float32), mode="drop"))
         return state._replace(sched=sched), jnp.where(finite, w, 0.0)
 
-    def train_aggregate(state, idx, mask, images, labels, sizes, d=None):
+    def train_aggregate(state, idx, mask, images, labels, sizes, d=None,
+                        frozen=None):
         """Local training of ``idx`` + store + aggregate (masked weights).
         ``mask is None`` marks the all-device initial round — fault
         injection only arms on real (masked) selections."""
-        state, rows = train_rows(state, idx, images, labels)
+        state, rows = train_rows(state, idx, images, labels, frozen)
         w = sizes[idx]
         if mask is not None:
             w = jnp.where(mask, w, 0.0)
@@ -497,13 +546,14 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
                               opt_state=opt_state)
 
     def init_round(state, images, labels, sizes, arr, inr_round,
-                   test_images, test_labels):
+                   test_images, test_labels, frozen=None):
         """Round 0 (Alg. 1 line 1 + Alg. 2): all devices train, aggregate,
         K-means-cluster on the chosen feature layer, evaluate + allocate.
         ``inr_round`` (dynamic interference, all devices active) folds into
         the allocation's rate; None otherwise."""
         all_idx = jnp.arange(N)
-        state = train_aggregate(state, all_idx, None, images, labels, sizes)
+        state = train_aggregate(state, all_idx, None, images, labels, sizes,
+                                frozen=frozen)
         with phase_scope("select"):
             feats = extract_features_flat(state.client_params,
                                           feature_layer, spec)
@@ -512,7 +562,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         state = state._replace(key=key, labels=k_labels.astype(jnp.int32))
         with phase_scope("eval"):
             acc0, _ = eval_fn(unflatten_vector(spec, state.params),
-                              test_images, test_labels)
+                              test_images, test_labels, **frozen_kw(frozen))
         state, arr = step_channel(state, arr)
         if inr_round is not None:
             arr = dict(arr)
@@ -554,7 +604,7 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
         return state, arr, idx, mask
 
     def finish_phase(state, arr, idx, mask, inr_round, images, labels,
-                     sizes, test_images, test_labels):
+                     sizes, test_images, test_labels, frozen=None):
         """allocate → train → aggregate → eval for one cell's selection.
         ``inr_round`` adds the round's selection-driven interference on top
         of any build-time ``inr`` before the solvers fold it into J."""
@@ -569,10 +619,11 @@ def build_round_phases(cfg: EngineConfig, aggregator, selector, allocator,
                 # an update past the deadline is a straggler the server
                 # abandons
                 d = completion_times(arr_sel, b_sel, f_sel, mask)
-        state = train_aggregate(state, idx, mask, images, labels, sizes, d)
+        state = train_aggregate(state, idx, mask, images, labels, sizes, d,
+                                frozen)
         with phase_scope("eval"):
             acc, _ = eval_fn(unflatten_vector(spec, state.params),
-                             test_images, test_labels)
+                             test_images, test_labels, **frozen_kw(frozen))
         return state, RoundOutputs(
             accuracy=acc, T=T, E=E, selected=idx, mask=mask,
             inr=None if inr_round is None else inr_round[0])
@@ -641,7 +692,7 @@ def _traced_round_program(cfg: EngineConfig, selector, allocator,
                and getattr(channel, "dynamic", False))
 
     def run(state, images, labels, sizes, arr, test_images, test_labels,
-            rounds: int, with_init: bool):
+            frozen=None, *, rounds: int, with_init: bool):
         arr = dict(arr)
         xg = arr.pop("xgain", None)          # [(cells,) N, C] cross gains
 
@@ -657,12 +708,13 @@ def _traced_round_program(cfg: EngineConfig, selector, allocator,
             if with_init:
                 state, init_out = init_round(state, images, labels, sizes,
                                              arr, None, test_images,
-                                             test_labels)
+                                             test_labels, frozen)
 
             def step(s, _):
                 s, arr_f, idx, mask = select_phase(s, arr)
                 return finish_phase(s, arr_f, idx, mask, None, images,
-                                    labels, sizes, test_images, test_labels)
+                                    labels, sizes, test_images, test_labels,
+                                    frozen)
         else:
             # ---- cells axis inside the program: inner vmap over cells,
             # one cross-cell interference reduction per round ------------
@@ -683,24 +735,26 @@ def _traced_round_program(cfg: EngineConfig, selector, allocator,
             sel_v = jax.vmap(select_phase)
             fin_v = jax.vmap(finish_phase,
                              in_axes=(0, 0, 0, 0, 0 if dynamic else None,
-                                      0, 0, 0, None, None))
+                                      0, 0, 0, None, None, None))
             init_v = jax.vmap(init_round,
                               in_axes=(0, 0, 0, 0, 0,
-                                       0 if dynamic else None, None, None))
+                                       0 if dynamic else None, None, None,
+                                       None))
 
             init_out = None
             if with_init:
                 inr0 = (cell_inr(jnp.ones((cells, N), jnp.float32))
                         if dynamic else None)
                 state, init_out = init_v(state, images, labels, sizes, arr,
-                                         inr0, test_images, test_labels)
+                                         inr0, test_images, test_labels,
+                                         frozen)
 
             def step(s, _):
                 s, arr_f, idx, mask = sel_v(s, arr)
                 inr_r = (cell_inr(dense_part(idx, mask))
                          if dynamic else None)
                 return fin_v(s, arr_f, idx, mask, inr_r, images, labels,
-                             sizes, test_images, test_labels)
+                             sizes, test_images, test_labels, frozen)
 
         state, outs = lax.scan(step, state, None, length=rounds)
         if init_out is None:
@@ -733,9 +787,11 @@ def run_rounds(cfg: EngineConfig, *, selector, allocator, aggregator,
     """The compiled multi-round experiment fn for one strategy bundle.
 
     Returns a jitted callable
-    ``(state, images, labels, sizes, arr, test_images, test_labels)
+    ``(state, images, labels, sizes, arr, test_images, test_labels, frozen)
     -> TracedRunResult`` executing ``rounds`` full FL rounds as ONE XLA
-    program (plus the Alg.-2 initial round when ``with_init``). With
+    program (plus the Alg.-2 initial round when ``with_init``). ``frozen``
+    is the workload's frozen tree (``frozen_params``; None for the CNN),
+    an argument like the data, shared by every lane. With
     ``cohort=True`` every data/state argument gains a leading cohort axis
     (vmapped) — the ``CohortRunner`` path; ``test_shared`` keeps the
     evaluation set un-mapped (one copy across the cohort).
@@ -808,14 +864,15 @@ def run_rounds(cfg: EngineConfig, *, selector, allocator, aggregator,
         core = functools.partial(prog, rounds=rounds, with_init=with_init)
         if cohort:
             test_ax = None if test_shared else 0
-            core = jax.vmap(core, in_axes=(0, 0, 0, 0, 0, test_ax, test_ax))
+            core = jax.vmap(core, in_axes=(0, 0, 0, 0, 0, test_ax, test_ax,
+                                           None))
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
                 data_spec = P("cohort")
                 test_spec = P() if test_shared else P("cohort")
                 core = jax.shard_map(
                     core, mesh=mesh,
-                    in_specs=(data_spec,) * 5 + (test_spec, test_spec),
+                    in_specs=(data_spec,) * 5 + (test_spec, test_spec, P()),
                     out_specs=data_spec, check_vma=False)
         # donate the carry: the (possibly [cohort, N, P]-sized) RoundState
         # buffers update in place across dispatches instead of double-

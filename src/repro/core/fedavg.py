@@ -999,7 +999,8 @@ class FLExperiment:
                 labels_sel = self._labels[jnp.asarray(idx_c)]
                 (state, T, E, cand, fired_cand, w_cand, good,
                  traces) = prog.plan(state, arr_f, idx, mask, self._sizes)
-                state, rows = prog.train(state, idx, images_sel, labels_sel)
+                state, rows = prog.train(state, idx, images_sel, labels_sel,
+                                         self.engine.frozen)
                 live = idx_h[mask_h]
                 # persist the GOOD lanes only (== mask when fault-free): a
                 # dropped/corrupted dispatch never reaches the store, exactly
@@ -1013,7 +1014,7 @@ class FLExperiment:
                 cand_rows = store.gather_staged(cand_h)
                 state, acc, div_cand, g_delta, ok_cand = prog.fire(
                     state, cand, cand_rows, w_cand, fired_cand,
-                    self.test_images, self.test_labels)
+                    self.test_images, self.test_labels, self.engine.frozen)
                 fired_h = np.asarray(fired_cand)
                 fired_ids = cand_h[fired_h]
                 store.release_staged(fired_ids)
@@ -1349,7 +1350,7 @@ class FLExperiment:
               else contextlib.nullcontext()):
             res = fn(state, self._images, self._labels,
                      self._sizes, fleet_arrays(self.fleet),
-                     self.test_images, self.test_labels)
+                     self.test_images, self.test_labels, self.engine.frozen)
         return with_init, res
 
     @staticmethod

@@ -12,6 +12,8 @@ Also provides a synthetic token stream for LM-scale FL experiments.
 """
 from __future__ import annotations
 
+import bisect
+
 import zlib
 from dataclasses import dataclass
 from typing import Dict, Tuple
@@ -74,13 +76,21 @@ def make_dataset(name: str, num_samples: int, seed: int = 0,
 
 def make_token_stream(vocab_size: int, num_tokens: int, seed: int = 0,
                       order: int = 2) -> np.ndarray:
-    """Markov token stream — gives LM training a learnable structure."""
+    """Markov token stream — gives LM training a learnable structure.
+
+    The stream ``rng.choice(ctx, p=trans[s])`` draws token by token: each
+    draw is one uniform double searched in the row's normalised CDF. The
+    uniforms are drawn in one call and searched with ``bisect``, which
+    gives the same tokens some fifty times faster."""
     rng = np.random.default_rng(seed)
     ctx = min(64, vocab_size)
     trans = rng.dirichlet(np.ones(ctx) * 0.1, size=ctx)
-    toks = np.zeros(num_tokens, np.int64)
+    cdf = np.cumsum(trans, axis=1)
+    cdf /= cdf[:, -1:]
+    rows = cdf.tolist()
+    toks = []
     s = 0
-    for i in range(num_tokens):
-        s = rng.choice(ctx, p=trans[s])
-        toks[i] = s % vocab_size
-    return toks.astype(np.int32)
+    for u in rng.random(num_tokens).tolist():
+        s = bisect.bisect_right(rows[s], u)
+        toks.append(s)
+    return (np.asarray(toks, np.int64) % vocab_size).astype(np.int32)

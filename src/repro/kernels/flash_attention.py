@@ -66,18 +66,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                             "interpret"))
+                                             "interpret", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = True,
+                    scale: float | None = None) -> jnp.ndarray:
     """q: [B, H, Sq, D]; k, v: [B, H, Sk, D] -> [B, H, Sq, D].
 
     GQA callers repeat KV heads up to H before the call (the wrapper in
-    ``repro.kernels.ops`` does this).
+    ``repro.kernels.ops`` does this). ``scale`` multiplies the scores
+    (None = 1/sqrt(D)). The call is named ``flash_attention`` in the
+    compiled program and in a profiler trace.
     """
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     bq = min(bq, Sq)
     bk = min(bk, Sk)
     pad_q = (-Sq) % bq
@@ -110,5 +114,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu.VMEM((bq, D), jnp.float32),        # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, Sqp, D)[:, :, :Sq]

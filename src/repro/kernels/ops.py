@@ -197,8 +197,9 @@ def chunked_pairwise(rows, centroids, *, chunk_size: int | None = None):
 
 
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
-              use_pallas: bool | None = None):
-    """GQA-aware attention. q: [B, S, H, D]; k, v: [B, S, K, D]."""
+              use_pallas: bool | None = None, scale: float | None = None):
+    """GQA-aware attention. q: [B, S, H, D]; k, v: [B, S, K, D]; ``scale``
+    multiplies the scores (None = 1/sqrt(D))."""
     use_pallas = _resolve_use_pallas("attention", use_pallas)
     B, Sq, H, D = q.shape
     K = k.shape[2]
@@ -211,10 +212,11 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     vt = v.transpose(0, 2, 1, 3)
     if use_pallas:
         kernel = functools.partial(_flash, causal=causal, window=window,
-                                   interpret=not _on_tpu())
+                                   interpret=not _on_tpu(), scale=scale)
         out = _on_plane_mesh(kernel, (_WHOLE,) * 3)(qt, kt, vt)
     else:
-        out = ref.flash_attention_ref(qt, kt, vt, causal=causal, window=window)
+        out = ref.flash_attention_ref(qt, kt, vt, causal=causal, window=window,
+                                      scale=scale)
     return out.transpose(0, 2, 1, 3)
 
 

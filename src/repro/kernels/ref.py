@@ -37,12 +37,16 @@ def flat_aggregate_ref(flat: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        window: int | None = None) -> jnp.ndarray:
-    """Plain softmax attention. q: [B, H, Sq, D]; k, v: [B, H, Sk, D]."""
+                        window: int | None = None,
+                        scale: float | None = None) -> jnp.ndarray:
+    """Plain softmax attention. q: [B, H, Sq, D]; k, v: [B, H, Sk, D].
+    ``scale`` multiplies the scores (None = 1/sqrt(D))."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(D))
+                        k.astype(jnp.float32))
+    logits = (logits / jnp.sqrt(jnp.float32(D)) if scale is None
+              else logits * scale)
     qpos = jnp.arange(Sq)[:, None] + (Sk - Sq)   # right-aligned positions
     kpos = jnp.arange(Sk)[None, :]
     mask = jnp.ones((Sq, Sk), bool)

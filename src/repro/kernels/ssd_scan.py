@@ -86,7 +86,8 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, interpret: bool = True):
     """x: [BH, S, P]; a: [BH, S]; b, c: [BH, S, N].
 
     Returns (y: [BH, S, P], final_state: [BH, P, N] fp32). S must not be
-    ragged; the wrapper pads with a=0, x=0 (identity steps).
+    ragged; the wrapper pads with a=0, x=0 (identity steps). The call is
+    named ``ssd_scan`` in the compiled program and in a profiler trace.
     """
     BH, S, P = x.shape
     N = b.shape[-1]
@@ -123,5 +124,6 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, interpret: bool = True):
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, a_cum, b, c)
     return y[:, :S], h

@@ -45,6 +45,18 @@ def silu(x):
     return x * jax.nn.sigmoid(x)
 
 
+def linear(x, p, name: str):
+    """``x @ p[name]``, plus a LoRA adapter's ``(x @ A) @ B`` beside it
+    where ``p`` holds one under ``name + "_lora"`` (the pair ``(A, B)``,
+    its scale folded into ``B``): the adapted weight is never formed."""
+    y = x @ p[name]
+    lora = p.get(name + "_lora")
+    if lora is not None:
+        a, b = lora
+        y = y + (x @ a) @ b
+    return y
+
+
 # ---------------------------------------------------------------------------
 # rotary position embedding
 # ---------------------------------------------------------------------------
@@ -114,16 +126,19 @@ def _attn_block(q_blk, k_blk, v_blk, q_pos, k_pos, causal, window, kv_valid):
 
 def blockwise_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                         q_positions=None, k_positions=None, kv_valid=None,
-                        q_chunk: int = 512, kv_chunk: int = 1024):
+                        q_chunk: int = 512, kv_chunk: int = 1024,
+                        scale: Optional[float] = None):
     """Memory-efficient attention: never materialises the [Sq, Sk] matrix.
 
     q: [B, Sq, H, D]; k, v: [B, Sk, K, D] with H % K == 0 (GQA).
     Positions default to aligned ranges (prefill). Output: [B, Sq, H, D].
+    ``scale`` multiplies the scores (None = 1/sqrt(D)).
     """
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
     G = H // K
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     if q_positions is None:
         q_positions = jnp.arange(Sq)
     if k_positions is None:
@@ -193,16 +208,19 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: Optional[int] =
     return out[:, :Sq].astype(q.dtype)
 
 
-def full_attention_1q(q, k, v, k_positions, q_position, *, window=None, kv_valid=None):
+def full_attention_1q(q, k, v, k_positions, q_position, *, window=None, kv_valid=None,
+                      scale: Optional[float] = None):
     """Single-query decode attention over a (possibly ring-buffer) cache.
 
     q: [B, 1, H, D]; k/v: [B, C, K, D]; k_positions: [B, C] absolute positions;
-    q_position: [B] absolute position of the new token.
+    q_position: [B] absolute position of the new token. ``scale`` as in
+    :func:`blockwise_attention`.
     """
     B, _, H, D = q.shape
     _, C, K, _ = k.shape
     G = H // K
-    qg = q.reshape(B, K, G, D).astype(jnp.float32) / math.sqrt(D)
+    qg = q.reshape(B, K, G, D).astype(jnp.float32)
+    qg = qg / math.sqrt(D) if scale is None else qg * scale
     logits = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32))
     mask = k_positions[:, None, None, :] <= q_position[:, None, None, None]
     if window is not None:
@@ -219,9 +237,9 @@ def attention_qkv(p, x, cfg: ModelConfig, kv_x=None):
     """Project hidden states to (q, k, v). ``kv_x`` enables cross-attention."""
     hd = cfg.resolved_head_dim
     kv_src = x if kv_x is None else kv_x
-    q = x @ p["wq"]
-    k = kv_src @ p["wk"]
-    v = kv_src @ p["wv"]
+    q = linear(x, p, "wq")
+    k = linear(kv_src, p, "wk")
+    v = linear(kv_src, p, "wv")
     if "bq" in p:
         q = q + p["bq"]
         k = k + p["bk"]
@@ -550,7 +568,7 @@ def mamba2_apply(p, x, cfg: ModelConfig, initial_state=None,
     s = cfg.ssm
     d_inner, n_heads, conv_ch = mamba2_split_dims(cfg)
     B, S, _ = x.shape
-    zxbcdt = x @ p["in_proj"]
+    zxbcdt = linear(x, p, "in_proj")
     z, xBC, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_ch], axis=-1)
     xBC = silu(causal_conv1d(xBC, p["conv_w"], p["conv_b"]))
     xs, Bm, Cm = jnp.split(xBC, [d_inner, d_inner + s.n_groups * s.d_state], axis=-1)
@@ -573,7 +591,7 @@ def mamba2_apply(p, x, cfg: ModelConfig, initial_state=None,
     Y = Y + p["D"][None, None, :, None] * xs.astype(jnp.float32)
     Y = Y.reshape(B, S, d_inner).astype(x.dtype)
     Y = rmsnorm(Y * silu(z), p["norm"], cfg.norm_eps)
-    out = Y @ p["out_proj"]
+    out = linear(Y, p, "out_proj")
     if return_state:
         return out, h_final
     return out

@@ -51,6 +51,12 @@ class ModelDef:
     ``make_dataset(cfg, num_samples, seed=...)`` (optional) builds the
     workload's synthetic dataset; ``None`` means the workload rides the
     image datasets selected by ``ExperimentSpec.dataset`` (the CNN path).
+    ``frozen(cfg)`` (optional) gives the tree every client shares and none
+    trains (a LoRA workload's base). The engine places it on the device
+    once per process and config (``repro.core.engine.frozen_params``) and
+    hands it to every program as an argument; ``loss`` and ``evaluate``
+    then take it as the keyword ``frozen``. ``None``: nothing is frozen,
+    and the hooks take no such keyword.
     """
     name: str
     init: Callable
@@ -58,6 +64,7 @@ class ModelDef:
     evaluate: Callable
     price_uploads: bool = False
     make_dataset: Any = None
+    frozen: Any = None
 
 
 def _cnn_evaluate(params, test_images, test_labels, *, cfg: CNNConfig):

@@ -73,12 +73,7 @@ def _layer_plan(cfg: ModelConfig):
     """Static per-layer (mixer, mlp) plan for one stack."""
     plan = []
     for i in range(cfg.num_layers):
-        if cfg.family == "ssm":
-            mixer = "mamba"
-        elif cfg.attn_period:
-            mixer = "attn" if (i % cfg.attn_period) == cfg.attn_period - 1 else "mamba"
-        else:
-            mixer = "attn"
+        mixer = "attn" if cfg.is_attention_layer(i) else "mamba"
         if cfg.family == "ssm":
             mlp = "none"
         elif cfg.moe is not None and (
@@ -146,33 +141,49 @@ def init_model(cfg: ModelConfig, key, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg: ModelConfig, x, y):
+    """``x + residual_multiplier · y`` (the plain sum where it is 1)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def _block_apply(p, x, cfg: ModelConfig, *, mixer: str, mlp: str,
                  causal: bool = True, window=None, positions=None,
                  memory=None, moe_impl: str = "dense",
                  q_chunk: int = 512, kv_chunk: int = 1024,
                  use_pallas=None):
-    """Full-sequence block application (train / prefill). Returns (x, aux)."""
+    """Full-sequence block application (train / prefill). Returns (x, aux).
+
+    The mixer and the MLP run under the named scopes ``lm.attn`` /
+    ``lm.mamba`` and ``lm.mlp`` (op metadata only)."""
     aux = jnp.zeros((), jnp.float32)
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
     if mixer == "attn":
-        q, k, v = L.attention_qkv(p["attn"], h, cfg)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
-        if ops.kernel_dispatch(use_pallas):
-            # flash-attention kernel under the dispatch policy (TPU /
-            # REPRO_FORCE_PALLAS / explicit opt-in); ops.attention owns
-            # the off-TPU interpret-mode warning
-            attn_out = ops.attention(q, k, v, causal=causal, window=window,
-                                     use_pallas=use_pallas)
-        else:
-            attn_out = L.blockwise_attention(
-                q, k, v, causal=causal, window=window,
-                q_positions=None, k_positions=None,
-                q_chunk=q_chunk, kv_chunk=kv_chunk)
-        B, S = x.shape[:2]
-        x = x + attn_out.reshape(B, S, -1) @ p["attn"]["wo"]
+        with jax.named_scope("lm.attn"):
+            q, k, v = L.attention_qkv(p["attn"], h, cfg)
+            if cfg.rope:
+                q = L.apply_rope(q, positions, cfg.rope_theta)
+                k = L.apply_rope(k, positions, cfg.rope_theta)
+            scale = cfg.attention_multiplier
+            if ops.kernel_dispatch(use_pallas):
+                # flash-attention kernel under the dispatch policy (TPU /
+                # REPRO_FORCE_PALLAS / explicit opt-in); ops.attention owns
+                # the off-TPU interpret-mode warning
+                attn_out = ops.attention(q, k, v, causal=causal,
+                                         window=window,
+                                         use_pallas=use_pallas, scale=scale)
+            else:
+                attn_out = L.blockwise_attention(
+                    q, k, v, causal=causal, window=window,
+                    q_positions=None, k_positions=None,
+                    q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale)
+            B, S = x.shape[:2]
+            x = _residual(cfg, x, attn_out.reshape(B, S, -1) @ p["attn"]["wo"])
     else:
-        x = x + L.mamba2_apply(p["mamba"], h, cfg, use_pallas=use_pallas)
+        with jax.named_scope("lm.mamba"):
+            x = _residual(cfg, x, L.mamba2_apply(p["mamba"], h, cfg,
+                                                 use_pallas=use_pallas))
     if memory is not None and "cross" in p:
         h = L.rmsnorm(x, p["ln_cross"], cfg.norm_eps)
         q, k, v = L.attention_qkv(p["cross"], h, cfg, kv_x=memory)
@@ -181,12 +192,14 @@ def _block_apply(p, x, cfg: ModelConfig, *, mixer: str, mlp: str,
         B, S = x.shape[:2]
         x = x + out.reshape(B, S, -1) @ p["cross"]["wo"]
     if mlp == "dense":
-        h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-        x = x + L.mlp_apply(p["mlp"], h)
+        with jax.named_scope("lm.mlp"):
+            h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+            x = _residual(cfg, x, L.mlp_apply(p["mlp"], h))
     elif mlp == "moe":
-        h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-        out, a = L.MOE_IMPLS[moe_impl](p["moe"], h, cfg.moe)
-        x = x + out
+        with jax.named_scope("lm.mlp"):
+            h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+            out, a = L.MOE_IMPLS[moe_impl](p["moe"], h, cfg.moe)
+            x = _residual(cfg, x, out)
         aux = aux + a
     return x, aux
 
@@ -196,14 +209,27 @@ def _block_apply(p, x, cfg: ModelConfig, *, mixer: str, mlp: str,
 # ---------------------------------------------------------------------------
 
 
-def _embed(cfg: ModelConfig, params, tokens):
-    return jnp.take(params["embed"], tokens, axis=0)
+def _embed(cfg: ModelConfig, params, tokens, dtype=None):
+    """Token embeddings, in ``dtype`` (None = the table's), scaled by
+    ``embedding_multiplier``."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if dtype is not None and x.dtype != dtype:
+        x = x.astype(dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
-def _unembed(cfg: ModelConfig, params, x):
+def unembed(cfg: ModelConfig, params, x):
+    """Logits of final-normed hidden states ``x`` (the tied embedding or
+    ``lm_head``), divided by ``logits_scaling``."""
     if cfg.tie_embeddings:
-        return x @ params["embed"].T
-    return x @ params["lm_head"]
+        logits = x @ params["embed"].T
+    else:
+        logits = x @ params["lm_head"]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _scan_stack(stacked, x, body, unroll: int = 1):
@@ -226,11 +252,17 @@ def _scan_stack(stacked, x, body, unroll: int = 1):
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *,
             moe_impl: str = "dense", q_chunk: int = 512, kv_chunk: int = 1024,
-            remat: bool = False, unroll: int = 1, use_pallas=None):
+            remat: bool = False, unroll: int = 1, use_pallas=None,
+            act_dtype=None, return_hidden: bool = False):
     """Returns (logits [B, S, V], aux_loss scalar).
 
     ``use_pallas`` selects the attention / SSD kernel route per the
-    ``repro.kernels.ops`` dispatch policy (None = follow the backend)."""
+    ``repro.kernels.ops`` dispatch policy (None = follow the backend).
+    ``act_dtype`` holds the activations in that dtype whatever the
+    weights' (None = the embedding table's). ``remat`` recomputes each
+    layer in the backward pass, keeping only the layer's input.
+    ``return_hidden`` returns the final-normed hidden states ``[B, S, D]``
+    in place of the logits (for a loss that applies the head itself)."""
     window = cfg.sliding_window
 
     if cfg.is_encoder_decoder:
@@ -240,14 +272,14 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *,
 
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = constrain(_embed(cfg, params, tokens), "act")
+    x = constrain(_embed(cfg, params, tokens, act_dtype), "act")
     if cfg.family == "vlm" and "image_embeds" in batch:
         img = batch["image_embeds"].astype(x.dtype)
         n_img = img.shape[1]
         x = jnp.concatenate([img, x[:, n_img:]], axis=1)
     positions = jnp.arange(S)
 
-    def make_body(mixer, mlp):
+    def make_body(mixer, mlp, prevent_cse=False):
         def body(lp, x):
             x, aux = _block_apply(lp, x, cfg, mixer=mixer, mlp=mlp,
                                   causal=True, window=window,
@@ -256,7 +288,7 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *,
                                   use_pallas=use_pallas)
             return constrain(x, "act"), aux
         if remat:
-            return jax.checkpoint(body, prevent_cse=False)
+            return jax.checkpoint(body, prevent_cse=prevent_cse)
         return body
 
     plan = _layer_plan(cfg)
@@ -266,7 +298,11 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *,
                              unroll=unroll)
     else:
         period = cfg.attn_period
-        bodies = [make_body(*plan[j]) for j in range(period)]
+        # a period's layers share one scan step, and a one-period stack's
+        # scan is inlined: there the recomputed layers would be merged with
+        # the forward pass (and every residual kept) unless CSE is barred
+        bodies = [make_body(*plan[j], prevent_cse=True)
+                  for j in range(period)]
 
         def group_body(gp, x):
             aux = jnp.zeros((), jnp.float32)
@@ -278,7 +314,9 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *,
         x, aux = _scan_stack(params["groups"], x, group_body, unroll=unroll)
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return constrain(_unembed(cfg, params, x), "logits"), aux
+    if return_hidden:
+        return x, aux
+    return constrain(unembed(cfg, params, x), "logits"), aux
 
 
 def _forward_encdec(cfg: ModelConfig, params, batch, *, moe_impl, q_chunk,
@@ -320,7 +358,7 @@ def _forward_encdec(cfg: ModelConfig, params, batch, *, moe_impl, q_chunk,
     body = jax.checkpoint(dec_body, prevent_cse=False) if remat else dec_body
     x, aux_d = _scan_stack(params["decoder"], dec_x, body, unroll=unroll)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return constrain(_unembed(cfg, params, x), "logits"), aux_e + aux_d
+    return constrain(unembed(cfg, params, x), "logits"), aux_e + aux_d
 
 
 # ---------------------------------------------------------------------------
@@ -430,8 +468,9 @@ def _attn_decode(p, h, cfg, lc, pos, window):
     C = lc["k"].shape[1]
     q, k, v = L.attention_qkv(p, h, cfg)
     pos_b = jnp.full((h.shape[0],), pos, jnp.int32)
-    q = L.apply_rope(q, pos_b[:, None], cfg.rope_theta)
-    k = L.apply_rope(k, pos_b[:, None], cfg.rope_theta)
+    if cfg.rope:
+        q = L.apply_rope(q, pos_b[:, None], cfg.rope_theta)
+        k = L.apply_rope(k, pos_b[:, None], cfg.rope_theta)
     slot = jnp.mod(pos, C)
     new_k = lax.dynamic_update_slice(lc["k"], k, (0, slot, 0, 0))
     new_v = lax.dynamic_update_slice(lc["v"], v, (0, slot, 0, 0))
@@ -441,7 +480,8 @@ def _attn_decode(p, h, cfg, lc, pos, window):
     out = L.full_attention_1q(q, new_k, new_v,
                               jnp.broadcast_to(new_kpos, (h.shape[0], C)),
                               pos_b, window=window,
-                              kv_valid=jnp.broadcast_to(valid, (h.shape[0], C)))
+                              kv_valid=jnp.broadcast_to(valid, (h.shape[0], C)),
+                              scale=cfg.attention_multiplier)
     out = out.reshape(h.shape[0], 1, -1) @ p["wo"]
     return out, {"k": new_k, "v": new_v, "k_pos": new_kpos}
 
@@ -460,7 +500,7 @@ def decode_step(cfg: ModelConfig, params, batch: Dict[str, Any], cache, *,
     def attn_block_decode(lp, x, lc):
         h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
         out, new_lc = _attn_decode(lp["attn"], h, cfg, lc, pos, window)
-        x = x + out
+        x = _residual(cfg, x, out)
         x = _mlp_decode(lp, x, cfg, moe_impl)
         return x, new_lc
 
@@ -468,7 +508,7 @@ def decode_step(cfg: ModelConfig, params, batch: Dict[str, Any], cache, *,
         h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
         y, ssm_state, conv_state = L.mamba2_decode(
             lp["mamba"], h[:, 0], cfg, lc["ssm_state"], lc["conv_state"])
-        x = x + y[:, None]
+        x = _residual(cfg, x, y[:, None])
         x = _mlp_decode(lp, x, cfg, moe_impl)
         return x, {"ssm_state": ssm_state, "conv_state": conv_state}
 
@@ -547,7 +587,7 @@ def decode_step(cfg: ModelConfig, params, batch: Dict[str, Any], cache, *,
         aux_cache["attn"] = new_attn
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(cfg, params, x)
+    logits = unembed(cfg, params, x)
     aux_cache["pos"] = pos + 1
     return logits, aux_cache
 
@@ -555,9 +595,9 @@ def decode_step(cfg: ModelConfig, params, batch: Dict[str, Any], cache, *,
 def _mlp_decode(lp, x, cfg, moe_impl):
     if "mlp" in lp:
         h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        x = x + L.mlp_apply(lp["mlp"], h)
+        x = _residual(cfg, x, L.mlp_apply(lp["mlp"], h))
     elif "moe" in lp:
         h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
         out, _ = L.MOE_IMPLS[moe_impl](lp["moe"], h, cfg.moe)
-        x = x + out
+        x = _residual(cfg, x, out)
     return x

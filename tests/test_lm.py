@@ -13,7 +13,7 @@ from repro.core.clustering import (adjusted_rand_index, kmeans_fit,
                                    kmeans_fit_minibatch)
 from repro.kernels import ops
 from repro.models.lm import (LMConfig, adapter_num_params, base_params,
-                             init_adapter, lm_loss, merge_lora)
+                             init_adapter, lm_loss, with_adapter)
 from repro.models.registry import (model_def_for, workload_config,
                                    workload_names)
 from repro.models.transformer import forward
@@ -103,11 +103,12 @@ def _tinyllama_cfg() -> LMConfig:
 def test_fresh_adapter_is_exact_noop_on_base():
     cfg = _tinyllama_cfg()
     adapter = init_adapter(cfg, jax.random.PRNGKey(3))
-    merged = merge_lora(cfg, adapter)
+    base = base_params(cfg)
+    adapted = with_adapter(cfg, base, adapter)
     toks = jax.random.randint(jax.random.PRNGKey(0), (2, cfg.seq_len), 0,
                               cfg.model.vocab_size)
-    ref, _ = forward(cfg.model, base_params(cfg), {"tokens": toks})
-    out, _ = forward(cfg.model, merged, {"tokens": toks})
+    ref, _ = forward(cfg.model, base, {"tokens": toks})
+    out, _ = forward(cfg.model, adapted, {"tokens": toks})
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
 
 
@@ -144,7 +145,8 @@ def test_lm_loss_is_finite_and_differentiable():
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, cfg.seq_len + 1), 0,
                               cfg.model.vocab_size)
     batch = {"images": toks, "labels": jnp.zeros((4,), jnp.int32)}
-    loss, g = jax.value_and_grad(lm_loss)(adapter, batch, cfg)
+    loss, g = jax.value_and_grad(lm_loss)(adapter, batch, cfg,
+                                          frozen=base_params(cfg))
     assert np.isfinite(float(loss))
     gnorm = sum(float(jnp.sum(jnp.abs(l)))
                 for l in jax.tree_util.tree_leaves(g))

@@ -120,6 +120,25 @@ def test_ssd_scan_compiles_at_mamba2_130m_widths(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kernel", ["ssd_scan", "flash_attention"])
+def test_granite_eval_kernels_compile_and_keep_their_names(one_chip, kernel):
+    """The kernels granite-4.0-h-micro's evaluation takes on a TPU, at its
+    widths over 4 test windows of 2048 tokens: each compiles, and its call
+    is named after the kernel (the ``<kernel>_roofline`` metrics find the
+    calls by that name in a trace)."""
+    if kernel == "ssd_scan":
+        fn = functools.partial(ssd_scan, chunk=256, interpret=False)
+        args = (_sds((256, 2048, 64), one_chip), _sds((256, 2048), one_chip),
+                _sds((256, 2048, 128), one_chip),
+                _sds((256, 2048, 128), one_chip))
+    else:
+        fn = functools.partial(flash_attention, causal=True, interpret=False,
+                               scale=0.015625)
+        args = (_sds((4, 32, 2048, 64), one_chip),) * 3
+    _, text = _compile(fn, *args)
+    assert f"%{kernel}." in text and "tpu_custom_call" in text
+
+
 # ---------------------------------------------------------------------------
 # programs
 # ---------------------------------------------------------------------------
@@ -132,7 +151,7 @@ def test_lora_local_update_compiles_with_kernel_route(one_chip, kernel_route,
     kernel route on — it failed before, differentiating through the
     forward-only kernels — while evaluation keeps the kernels."""
     from repro.core.engine import make_local_update
-    from repro.models.lm import init_adapter, lm_evaluate
+    from repro.models.lm import base_params, init_adapter, lm_evaluate
     from repro.models.registry import workload_config
 
     cfg = workload_config(arch)
@@ -140,14 +159,18 @@ def test_lora_local_update_compiles_with_kernel_route(one_chip, kernel_route,
                              jax.ShapeDtypeStruct((2,), jnp.uint32))
     adapter = jax.tree_util.tree_map(
         lambda x: _sds(x.shape, one_chip, x.dtype), adapter)
+    frozen = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, one_chip, x.dtype),
+        jax.eval_shape(functools.partial(base_params, cfg)))
     windows = _sds((8, cfg.seq_len + 1), one_chip, jnp.int32)
     dialects = _sds((8,), one_chip, jnp.int32)
     key = _sds((2,), one_chip, jnp.uint32)
     update = make_local_update(cfg, 0.05, local_iters=2, batch_size=4)
-    _, text = _compile(update, adapter, windows, dialects, key)
+    _, text = _compile(update, adapter, windows, dialects, key, frozen)
     assert "tpu_custom_call" not in text
-    _, text = _compile(functools.partial(lm_evaluate, cfg=cfg), adapter,
-                       windows, dialects)
+    _, text = _compile(
+        lambda a, w, d, f: lm_evaluate(a, w, d, cfg=cfg, frozen=f),
+        adapter, windows, dialects, frozen)
     assert "tpu_custom_call" in text
 
 

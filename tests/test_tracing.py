@@ -42,7 +42,7 @@ def compiled_op_names(exp, rounds=2):
                     quarantine_after=exp.quarantine_after)
     text = fn.lower(exp.traced_state(), exp._images, exp._labels,
                     exp._sizes, fleet_arrays(exp.fleet), exp.test_images,
-                    exp.test_labels).compile().as_text()
+                    exp.test_labels, exp.engine.frozen).compile().as_text()
     names, region = [], False
     for line in text.splitlines():
         if line and not line[0].isspace():
@@ -121,6 +121,29 @@ def test_async_tick_names_its_phases_too():
     assert {"select", "allocate", "train", "aggregate", "eval"} <= phases
 
 
+GRANITE = dict(model="granite-4.0-h-smoke", clients=4, train_samples=20,
+               test_samples=2, samples_per_client=4, devices_per_round=2,
+               num_clusters=2, local_iters=1, batch_size=1, rounds=1,
+               learning_rate=0.02)
+
+
+def test_lm_layers_are_named_under_train_and_eval():
+    """The hybrid LM's mixers, MLPs and head carry ``lm.*`` scopes nested
+    under the round phase that ran them (forward and backward in
+    ``fl.train``, the forward in ``fl.eval``)."""
+    names = compiled_op_names(build_experiment(ExperimentSpec(**GRANITE)),
+                              rounds=1)
+    for phase in ("train", "eval"):
+        scoped = {layer for n in names if phase_of(n) == phase
+                  for layer in ("lm.mamba", "lm.attn", "lm.mlp", "lm.loss")
+                  if layer in n}
+        assert scoped == {"lm.mamba", "lm.attn", "lm.mlp", "lm.loss"}, phase
+    backward = {layer for n in names if "transpose(" in n
+                for layer in ("lm.mamba", "lm.attn", "lm.mlp", "lm.loss")
+                if layer in n}
+    assert backward == {"lm.mamba", "lm.attn", "lm.mlp", "lm.loss"}
+
+
 # ---------------------------------------------------------------------------
 # host spans and the cache-miss marker
 # ---------------------------------------------------------------------------
@@ -181,3 +204,35 @@ def test_fl_sim_profile_writes_a_trace_with_the_spans(tmp_path, capsys):
     assert "final_accuracy" in capsys.readouterr().out
     names = {e[0] for e in host_events(str(tmp_path / "prof"))}
     assert {"repro.build", "repro.run", "repro.run.wait"} <= names
+
+
+def test_frozen_base_is_placed_once_per_process_and_config(tmp_path):
+    """``repro.build.frozen`` spans the base's placement, and
+    ``repro.count.frozen_upload`` (id ``config``) marks it once per process
+    and config, not once per experiment."""
+    from repro.core.engine import frozen_params
+
+    frozen_params.cache_clear()
+    # a learning rate no other test uses: the first build makes a new
+    # engine, which asks for the base
+    spec = ExperimentSpec(**dict(GRANITE, learning_rate=0.0173))
+
+    def two_experiments():
+        for seed in (1, 2):
+            build_experiment(spec.replace(seed=seed)).run()
+
+    _, events = traced(tmp_path / "first", two_experiments)
+    uploads = [e for e in events if e[0] == "repro.count.frozen_upload"]
+    spans = [e for e in events if e[0] == "repro.build.frozen"]
+    assert [e[3]["config"] for e in uploads] == ["granite-4.0-h-smoke"]
+    assert len(spans) == 1 and spans[0][3]["config"] == "granite-4.0-h-smoke"
+    builds = [e for e in events if e[0] == "repro.build"]
+    assert _encloses(builds[0], spans[0])
+    _, events = traced(tmp_path / "second", two_experiments)
+    assert not [e for e in events if "frozen" in e[0]]
+
+
+def test_the_cnn_places_no_frozen_tree(tmp_path):
+    _, events = traced(tmp_path, lambda: build_experiment(
+        ExperimentSpec(**dict(TINY, seed=3))).run())
+    assert not [e for e in events if "frozen" in e[0]]
