@@ -49,8 +49,7 @@ class LMConfig:
     key, exactly as ``CNNConfig`` is for the paper CNN.
 
     ``base_dtype`` is the frozen base's storage dtype (the adapters, their
-    gradients and SGD stay float32, and so do the activations). ``remat``
-    recomputes each layer in the backward pass."""
+    gradients and SGD stay float32, and so do the activations)."""
     model: ModelConfig              # the frozen-base transformer architecture
     seq_len: int = 32               # tokens per training window
     rank: int = 4                   # LoRA rank r
@@ -59,7 +58,6 @@ class LMConfig:
     num_dialects: int = 10          # synthetic dialects = "classes" for
                                     # non-iid partitioning and per_class eval
     base_dtype: str = "float32"
-    remat: bool = False
 
     @property
     def name(self) -> str:
@@ -233,10 +231,12 @@ def lm_loss(adapter, batch, cfg: LMConfig, *, frozen):
     The training forward always takes the jnp attention / SSD path
     (``use_pallas=False``): the flash-attention and SSD Pallas kernels are
     forward-only (no VJP), and the local update differentiates this loss.
-    Evaluation (:func:`lm_evaluate`) keeps the backend's kernel route."""
+    Evaluation (:func:`lm_evaluate`) keeps the backend's kernel route.
+    Each layer is recomputed in the backward pass, so that only the
+    layers' inputs stay alive across a long window."""
     tokens = batch["images"]
     params, h = _hidden(cfg, frozen, adapter, tokens[:, :-1],
-                        use_pallas=False, remat=cfg.remat)
+                        use_pallas=False, remat=True)
     targets = tokens[:, 1:]
 
     def nll_sum(h, t):
@@ -288,12 +288,10 @@ register_workload("mamba2-130m",
                   lambda: LMConfig(model=get_smoke_config("mamba2-130m")))
 #: one period of granite-4.0-h-micro at its published widths (the depth
 #: one chip holds as one of four pipeline stages): rank-16 adapters over a
-#: bfloat16 base, 2k-token windows, each layer recomputed in the backward
-#: pass
+#: bfloat16 base, 2k-token windows
 register_workload("granite-4.0-h-micro", lambda: LMConfig(
     model=granite_h_micro.one_period(), seq_len=2048, rank=16, alpha=32.0,
-    base_dtype="bfloat16", remat=True))
+    base_dtype="bfloat16"))
 #: the same stack at CPU widths (granite_4_0_h_micro.smoke_config)
 register_workload("granite-4.0-h-smoke", lambda: LMConfig(
-    model=granite_h_micro.smoke_config(), seq_len=32, rank=4, alpha=8.0,
-    remat=True))
+    model=granite_h_micro.smoke_config(), seq_len=32, rank=4, alpha=8.0))
